@@ -315,11 +315,12 @@ import json, time
 import jax, jax.numpy as jnp, numpy as np
 from repro.core.batched import (LayerTask, per_layer_sharded_dispatch,
                                 plan_buckets, quantize_layer_batch)
+from repro.launch.mesh import make_model_mesh
 from repro.models.modules import QSpec
 
 m, n, L, reps = {m}, {n}, {L}, {reps}
 rng = np.random.default_rng(0)
-mesh = jax.make_mesh((len(jax.devices()),), ("model",))
+mesh = make_model_mesh()
 qspec = QSpec(bits=2, group_size=64, rank=16)
 Ws = [jnp.asarray(rng.normal(size=(m, n)), jnp.float32) for _ in range(L)]
 Hs = []
@@ -366,11 +367,12 @@ import json, time
 import jax, jax.numpy as jnp, numpy as np
 from repro.core.batched import LayerTask, plan_buckets, quantize_layer_batch
 from repro.core.costmodel import CostModel, calibrate
+from repro.launch.mesh import make_model_mesh
 from repro.models.modules import QSpec
 
 m, n, L, reps = {m}, {n}, {L}, {reps}
 rng = np.random.default_rng(0)
-mesh = jax.make_mesh((len(jax.devices()),), ("model",))
+mesh = make_model_mesh()
 cal = calibrate(mesh, path="/tmp/repro_costcal_bench.json", force=True)
 cm = CostModel(cal)
 qspec = QSpec(bits=2, group_size=64, rank=16)
@@ -444,30 +446,34 @@ print("RESULT " + json.dumps({{
 """
 
 
+def _run_cpu_child(code: str, env: dict) -> dict:
+    """Run a benchmark snippet in a fresh process on the CPU and return
+    its ``RESULT`` line.  The child sets ``JAX_PLATFORMS=cpu`` so it never
+    competes with this process for an accelerator; a failing child
+    raises."""
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    env = dict(env, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.path.abspath(src) + os.pathsep +
+               env.get("PYTHONPATH", ""))
+    proc = subprocess.run([sys.executable, "-c", code], env=env,
+                          capture_output=True, text=True, timeout=1200)
+    if proc.returncode != 0:
+        raise RuntimeError(f"benchmark child failed (exit "
+                           f"{proc.returncode}):\n{proc.stderr[-4000:]}")
+    line = [ln for ln in proc.stdout.splitlines()
+            if ln.startswith("RESULT ")][-1]
+    return json.loads(line[len("RESULT "):])
+
+
 def _cold_start_row(m: int = 512, n: int = 512, n_layers: int = 8) -> dict:
     """Run the cold-start snippet in two fresh subprocesses sharing one
     cache directory: run 1 populates it (miss), run 2 deserializes
     (hit)."""
     import tempfile
-    env = dict(os.environ)
-    src = os.path.join(os.path.dirname(__file__), "..", "src")
-    env["PYTHONPATH"] = os.path.abspath(src) + os.pathsep + \
-        env.get("PYTHONPATH", "")
     code = textwrap.dedent(_COLDSTART_SNIPPET).format(m=m, n=n, L=n_layers)
-    runs = []
     with tempfile.TemporaryDirectory() as d:
-        env["REPRO_BENCH_CACHE"] = d
-        for _ in range(2):
-            proc = subprocess.run([sys.executable, "-c", code], env=env,
-                                  capture_output=True, text=True,
-                                  timeout=1200)
-            if proc.returncode != 0:
-                return {"m": m, "n": n, "n_layers": n_layers,
-                        "error": proc.stderr.strip().splitlines()[-1:]}
-            line = [ln for ln in proc.stdout.splitlines()
-                    if ln.startswith("RESULT ")][-1]
-            runs.append(json.loads(line[len("RESULT "):]))
-    cold, warm = runs
+        env = dict(os.environ, REPRO_BENCH_CACHE=d)
+        cold, warm = [_run_cpu_child(code, env) for _ in range(2)]
     return {"method": "rtn", "m": m, "n": n, "n_layers": n_layers,
             "cold_first_call_s": cold["first_call_s"],
             "warm_first_call_s": warm["first_call_s"],
@@ -485,19 +491,9 @@ def _sharded_bucket_row(m: int, n: int, n_layers: int,
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
                         f" --xla_force_host_platform_device_count="
                         f"{n_devices}").strip()
-    src = os.path.join(os.path.dirname(__file__), "..", "src")
-    env["PYTHONPATH"] = os.path.abspath(src) + os.pathsep + \
-        env.get("PYTHONPATH", "")
     code = textwrap.dedent(snippet).format(m=m, n=n, L=n_layers,
                                            reps=REPS)
-    proc = subprocess.run([sys.executable, "-c", code], env=env,
-                          capture_output=True, text=True, timeout=1200)
-    if proc.returncode != 0:
-        return {"m": m, "n": n, "n_layers": n_layers,
-                "error": proc.stderr.strip().splitlines()[-1:]}
-    line = [ln for ln in proc.stdout.splitlines()
-            if ln.startswith("RESULT ")][-1]
-    return json.loads(line[len("RESULT "):])
+    return _run_cpu_child(code, env)
 
 
 def run() -> dict:
@@ -540,15 +536,11 @@ def run() -> dict:
                              [(64, 64, 16), (128, 128, 16)]):
         row = _sharded_bucket_row(m, n, n_layers)
         sharded_rows.append(row)
-        if "error" in row:
-            print(f"  sharded bucket {m}x{n}: failed {row['error']}",
-                  flush=True)
-        else:
-            print(f"  sharded bucket {m}x{n} x{n_layers} "
-                  f"({row['n_devices']} dev): "
-                  f"per-layer={row['per_layer_sharded_s']}s "
-                  f"fused={row['sharded_batched_s']}s "
-                  f"({row['speedup']}x)", flush=True)
+        print(f"  sharded bucket {m}x{n} x{n_layers} "
+              f"({row['n_devices']} dev): "
+              f"per-layer={row['per_layer_sharded_s']}s "
+              f"fused={row['sharded_batched_s']}s "
+              f"({row['speedup']}x)", flush=True)
 
     hg = _health_guard_row(rng)
     print(f"  health guard {hg['m']}x{hg['n']} x{hg['n_layers']}: "
@@ -577,23 +569,17 @@ def run() -> dict:
           flush=True)
 
     lq = _sharded_bucket_row(64, 64, 16, snippet=_LOFTQ_SHARDED_SNIPPET)
-    if "error" in lq:
-        print(f"  loftq sharded bucket: failed {lq['error']}", flush=True)
-    else:
-        print(f"  loftq planner bucket 64x64 x16 ({lq['n_devices']} dev): "
-              f"replicated={lq['replicated_batched_s']}s "
-              f"sharded={lq['sharded_batched_s']}s -> "
-              f"chose {lq['chosen_path']} ({lq['speedup']}x vs worst)",
-              flush=True)
+    print(f"  loftq planner bucket 64x64 x16 ({lq['n_devices']} dev): "
+          f"replicated={lq['replicated_batched_s']}s "
+          f"sharded={lq['sharded_batched_s']}s -> "
+          f"chose {lq['chosen_path']} ({lq['speedup']}x vs worst)",
+          flush=True)
 
     cs = _cold_start_row()
-    if "error" in cs:
-        print(f"  cold start: failed {cs['error']}", flush=True)
-    else:
-        print(f"  cold start rtn {cs['m']}x{cs['n']} x{cs['n_layers']}: "
-              f"cold={cs['cold_first_call_s']}s "
-              f"warm={cs['warm_first_call_s']}s ({cs['speedup']}x, "
-              f"warm hits={cs['warm_hits']})", flush=True)
+    print(f"  cold start rtn {cs['m']}x{cs['n']} x{cs['n_layers']}: "
+          f"cold={cs['cold_first_call_s']}s "
+          f"warm={cs['warm_first_call_s']}s ({cs['speedup']}x, "
+          f"warm hits={cs['warm_hits']})", flush=True)
 
     out = {"rows": rows,
            "batched_rows": batched_rows,
@@ -626,9 +612,8 @@ def run() -> dict:
     # their cache tallies are mirrored into this process's registry.
     from repro.obs import metrics as obs_metrics
     from repro.obs import names as obs_names
-    if "error" not in cs:
-        obs_metrics.counter(obs_names.CACHE_HITS).inc(cs["warm_hits"])
-        obs_metrics.counter(obs_names.CACHE_MISSES).inc(cs["cold_misses"])
+    obs_metrics.counter(obs_names.CACHE_HITS).inc(cs["warm_hits"])
+    obs_metrics.counter(obs_names.CACHE_MISSES).inc(cs["cold_misses"])
     obs_metrics.save(os.path.join(RESULTS, "metrics-table10.json"))
     return out
 

@@ -24,6 +24,7 @@ from repro.core.cloq import (cloq_init, cloq_init_sharded, lowrank_objective,
                              regularize_gram)
 from repro.core.optq import optq_quantize, optq_quantize_sharded
 from repro.core.quantizer import QuantConfig
+from repro.launch.mesh import make_model_mesh
 
 rng = np.random.default_rng(0)
 m, n, rank = 128, 512, 32
@@ -31,7 +32,7 @@ W = jnp.asarray(rng.normal(size=(m, n)), jnp.float32)
 X = jnp.asarray(rng.normal(size=(4096, m)), jnp.float32)
 H = X.T @ X
 
-mesh = jax.make_mesh((8,), ("model",))
+mesh = make_model_mesh(8)
 cfg = QuantConfig(bits=2, group_size=64)
 
 print(f"quantizing W {W.shape} INT{cfg.bits} over {len(jax.devices())} devices")

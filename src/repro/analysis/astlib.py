@@ -31,8 +31,7 @@ MAP_WRAPPERS = {"jax.vmap", "vmap", "jax.lax.map", "jax.checkpoint",
                 "jax.remat", "jax.grad", "jax.value_and_grad",
                 "jax.eval_shape", "jax.make_jaxpr"}
 # wrappers that additionally BIND mesh axis names over their operand
-AXIS_WRAPPERS = {"shard_map", "jax.experimental.shard_map.shard_map",
-                 "jax.pmap", "pmap", "xmap"}
+AXIS_WRAPPERS = {"shard_map", "jax.shard_map", "jax.pmap", "pmap", "xmap"}
 TRACE_WRAPPERS = JIT_WRAPPERS | MAP_WRAPPERS | AXIS_WRAPPERS
 
 

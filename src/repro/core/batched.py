@@ -75,6 +75,7 @@ from repro.obs import log as obs_log
 from repro.obs import metrics as obs_metrics
 from repro.obs import names as obs_names
 from repro.obs import trace as obs_trace
+from repro.utils import solver_precision
 
 Array = jax.Array
 
@@ -230,6 +231,7 @@ def quantize_single(W: Array, H: Array | None, key: Array,
     return quantize_single_deq(W, H, key, spec, axis)[0]
 
 
+@solver_precision()     # f32 solver products on every backend
 def quantize_single_deq(W: Array, H: Array | None, key: Array,
                         spec: BucketSpec,
                         axis: str | None = None) -> tuple[dict, Array]:
@@ -388,11 +390,17 @@ def run_bucket_sequential(Ws: Array, Hs: Array | None, keys: Array,
 
     The cost model picks this path only through its memory gate — a
     bucket whose stacked ``(L, m, n)`` working set exceeds the calibrated
-    budget would thrash if vmapped, so it trades ``L`` dispatch overheads
-    for peak memory ``1/L`` of the fused path."""
-    outs = [_run_single(Ws[j], None if Hs is None else Hs[j], keys[j],
-                        requeue_spec(spec))
-            for j in range(Ws.shape[0])]
+    budget would not fit if vmapped, so it trades ``L`` dispatch overheads
+    for peak memory ``1/L`` of the fused path.  ``Ws``/``Hs`` may be
+    per-layer lists (:func:`_stage_bucket` stacks nothing for this path),
+    and at most two layers are on the device at once: each dispatch waits
+    for the layer before the previous one."""
+    outs: list[dict] = []
+    for j in range(len(Ws)):
+        if len(outs) >= 2:
+            jax.block_until_ready(outs[-2])
+        outs.append(_run_single(Ws[j], None if Hs is None else Hs[j],
+                                keys[j], requeue_spec(spec)))
     return {k: jnp.stack([o[k] for o in outs]) for k in outs[0]}
 
 
@@ -416,7 +424,6 @@ def _sharded_eval_executable(spec: BucketSpec, mesh, axis: str):
     """Compiled shard_map(vmap(eval_single)) for one (spec, mesh) pair —
     the sweep's distributed path: each device quantizes + scores its
     column shard, one scalar-per-layer psum totals the proxy errors."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     if spec.has_gram:
@@ -430,7 +437,7 @@ def _sharded_eval_executable(spec: BucketSpec, mesh, axis: str):
                 W, None, k, spec, axis=axis))(Ws_l, keys_l)
         in_specs = (P(None, None, axis), P(None, None))
 
-    fn = shard_map(local, mesh=mesh, in_specs=in_specs, out_specs=P(None))
+    fn = jax.shard_map(local, mesh=mesh, in_specs=in_specs, out_specs=P(None))
     return jax.jit(fn)
 
 
@@ -493,7 +500,6 @@ def _sharded_executable(spec: BucketSpec, mesh, axis: str):
     executable, mirroring ``run_bucket``'s jit cache.  Bounded so a
     long-lived process sweeping many distinct meshes doesn't pin compiled
     executables (and their Mesh references) forever."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     out_specs = bucket_out_specs(spec.method, axis)
@@ -509,7 +515,8 @@ def _sharded_executable(spec: BucketSpec, mesh, axis: str):
                 W, None, k, spec, axis=axis))(Ws_l, keys_l)
         in_specs = (P(None, None, axis), P(None, None))
 
-    fn = shard_map(local, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs)
     return jax.jit(fn)
 
 
@@ -687,13 +694,18 @@ def _stage_bucket(tasks: list[LayerTask], idxs: list[int],
     """Host-side staging of one bucket: stack (W, H, key) to device arrays.
 
     This is the host work the streaming executor overlaps with device
-    compute of the previous bucket."""
+    compute of the previous bucket.  A bucket the memory gate sent down
+    the sequential path stacks nothing: its layers go to the device one
+    at a time (:func:`run_bucket_sequential`)."""
+    keys = jnp.stack([tasks[i].key for i in idxs])
+    if spec.exec_path == "sequential":
+        return ([tasks[i].W for i in idxs],
+                [tasks[i].H for i in idxs] if spec.has_gram else None, keys)
     Ws = jnp.stack([jnp.asarray(tasks[i].W, jnp.float32) for i in idxs])
     Hs = None
     if spec.has_gram:
         Hs = jnp.stack([jnp.asarray(tasks[i].H, jnp.float32)
                         for i in idxs])
-    keys = jnp.stack([tasks[i].key for i in idxs])
     return Ws, Hs, keys
 
 

@@ -7,9 +7,10 @@ quantization residual ``dW = W - Q``, the optimal rank-r adapters minimizing
 
 are any factorization of ``R^{-1} LR_r(R dW)`` where ``R = S_H^{1/2} U_H^T``
 is the non-symmetric root of ``H`` (H = R^T R) and ``LR_r`` the best rank-r
-approximation (Eckart–Young).  Exactly two eigendecompositions/SVDs:
-``eigh(H)`` (m x m) and ``svd(R dW)`` (m x n) — independent of the
-calibration-set size.
+approximation (Eckart–Young).  Exactly two symmetric eigendecompositions:
+``eigh(H)`` (m x m) for the root, and the top-r SVD of ``R dW`` through the
+``eigh`` of its smaller Gram (:func:`repro.core.loftq.svd_lowrank_topr`) —
+independent of the calibration-set size.
 
 Splits of ``A B^T = R^{-1} U_{:r} S_{:r} V_{:r}^T`` (paper Table 7):
     "paper" : A = R^{-1} U S,      B = V        (best; default)
@@ -31,9 +32,14 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.core.linalg import cholesky_lower, tri_inv_lower
+
 Array = jax.Array
 
 SPLITS = ("paper", "bsigma", "sqrt")
+# diagonal floor of the Cholesky root, in units of the mean eigenvalue:
+# ten times what an f32 Cholesky of a rank-deficient Gram needs
+CHOL_FLOOR = 1e-5
 
 
 def regularize_gram(H: Array, lambda_frac: float = 0.01) -> Array:
@@ -42,19 +48,38 @@ def regularize_gram(H: Array, lambda_frac: float = 0.01) -> Array:
     return H + (lam + 1e-8) * jnp.eye(m, dtype=H.dtype)
 
 
-def gram_root(H: Array, eps: float = 1e-10):
-    """Non-symmetric root R = S^{1/2} U^T with H = R^T R, plus its inverse.
+def chol_root(H: Array):
+    """The TPU's root of :func:`gram_root`: ``R = L^T`` from the Cholesky
+    factor of ``H + CHOL_FLOOR * tr(H)/m * I``.  The floor lets an
+    unregularized, rank-deficient H factor in f32, so ``Rinv`` acts as
+    the pseudo-inverse path of Theorem 3.1's remark; on a regularized
+    Gram it adds a thousandth of the damping."""
+    m = H.shape[0]
+    L = cholesky_lower(H + CHOL_FLOOR * jnp.trace(H) / m
+                       * jnp.eye(m, dtype=H.dtype))
+    return L.T, tri_inv_lower(L).T
 
-    Rank-deficient H: eigenvalues are floored at ``eps * max_eig`` so that
-    ``Rinv`` acts as the pseudo-inverse path of Theorem 3.1's remark."""
+
+def gram_root(H: Array, eps: float = 1e-10):
+    """A root R with H = R^T R, plus its inverse.
+
+    Theorem 3.1's adapters do not depend on which root: any two differ by
+    an orthogonal factor that the SVD of ``R dW`` absorbs.  On a TPU the
+    root is the Cholesky factor (:func:`chol_root`; an ``m x m``
+    eigendecomposition there is minutes to compile or to run).
+    Elsewhere it is the paper's ``R = S^{1/2} U^T`` from ``eigh(H)``,
+    where a rank-deficient H has its eigenvalues floored at
+    ``eps * max_eig`` so that ``Rinv`` acts as the pseudo-inverse path of
+    the theorem's remark."""
     H = jnp.asarray(H, jnp.float32)
-    evals, evecs = jnp.linalg.eigh(H)
-    floor = eps * jnp.maximum(evals[-1], 1e-30)
-    ev = jnp.maximum(evals, floor)
-    sq = jnp.sqrt(ev)
-    R = sq[:, None] * evecs.T
-    Rinv = evecs * (1.0 / sq)[None, :]
-    return R, Rinv
+
+    def eig_root(h):
+        evals, evecs = jnp.linalg.eigh(h)
+        floor = eps * jnp.maximum(evals[-1], 1e-30)
+        sq = jnp.sqrt(jnp.maximum(evals, floor))
+        return sq[:, None] * evecs.T, evecs * (1.0 / sq)[None, :]
+
+    return jax.lax.platform_dependent(H, tpu=chol_root, default=eig_root)
 
 
 def split_factors(RinvU: Array, S: Array, V: Array, split: str):
@@ -76,13 +101,9 @@ def cloq_init(H: Array, dW: Array, rank: int, split: str = "paper"):
     (A (m,r), B (n,r)).  Vmap-safe: only ``rank``/``split`` are static, so
     the batched engine maps it over stacked (H, dW) buckets (and the
     shared-block driver over per-site Grams with a fixed dW)."""
-    dW = jnp.asarray(dW, jnp.float32)
     R, Rinv = gram_root(H)
-    M = R @ dW
-    U, S, Vt = jnp.linalg.svd(M, full_matrices=False)
-    r = rank
-    A, B = split_factors(Rinv @ U[:, :r], S[:r], Vt[:r, :].T, split)
-    return A, B
+    return cloq_lowrank_local(R, Rinv, jnp.asarray(dW, jnp.float32), rank,
+                              split)
 
 
 def lowrank_objective(H: Array, dW: Array, A: Array, B: Array) -> float:
@@ -129,10 +150,9 @@ def cloq_lowrank_local(R: Array, Rinv: Array, dW_local: Array, rank: int,
     Safe under both ``shard_map`` (the psum is the only communication) and
     ``vmap`` (the batched engine maps it over a stacked ``(L, m, n_local)``
     bucket inside one ``shard_map`` — psum then reduces a ``(L, m, m)``
-    stack in one collective).  Uses ``eigh`` of the m x m Gram rather than
-    the unsharded path's ``svd(R dW)``: the same subspace to float precision
-    (tests compare the ``A B^T`` product, which is the well-defined
-    quantity).  The Gram-trick core is shared with sharded LoftQ
+    stack in one collective).  With ``axis=None`` it is the whole solve of
+    :func:`cloq_init`.  Tests compare the ``A B^T`` product, the
+    well-defined quantity.  The Gram-trick core is shared with LoftQ
     (:func:`repro.core.loftq.svd_lowrank_topr`) — this is the ``R != I``
     instance."""
     from repro.core.loftq import svd_lowrank_topr
@@ -173,7 +193,6 @@ def cloq_site_lora(Hs: Array, dW: Array, rank: int, split: str = "paper",
             lambda H: cloq_init(regularize_gram(H, lambda_frac), dW, rank,
                                 split))(Hs)
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     Rs, Rinvs = jax.vmap(
         lambda H: gram_root(regularize_gram(H, lambda_frac)))(Hs)
@@ -182,10 +201,10 @@ def cloq_site_lora(Hs: Array, dW: Array, rank: int, split: str = "paper",
         return jax.vmap(lambda R, Rinv: cloq_lowrank_local(
             R, Rinv, dW_l, rank, split, axis))(Rs_, Rinvs_)
 
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(P(None, None, None), P(None, None, None),
-                             P(None, axis)),
-                   out_specs=(P(None, None, None), P(None, axis, None)))
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(P(None, None, None), P(None, None, None),
+                                 P(None, axis)),
+                       out_specs=(P(None, None, None), P(None, axis, None)))
     return fn(Rs, Rinvs, dW)
 
 
@@ -199,7 +218,6 @@ def cloq_init_sharded(H: Array, dW: Array, rank: int, mesh,
     :func:`repro.core.batched.run_bucket_sharded`.
     """
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     R, Rinv = gram_root(jnp.asarray(H, jnp.float32))
     dW = jnp.asarray(dW, jnp.float32)
@@ -207,7 +225,7 @@ def cloq_init_sharded(H: Array, dW: Array, rank: int, mesh,
     def local(R_, Rinv_, dW_l):
         return cloq_lowrank_local(R_, Rinv_, dW_l, rank, split, axis)
 
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(P(None, None), P(None, None), P(None, axis)),
-                   out_specs=(P(None, None), P(axis, None)))
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(P(None, None), P(None, None), P(None, axis)),
+                       out_specs=(P(None, None), P(axis, None)))
     return fn(R, Rinv, dW)

@@ -30,7 +30,9 @@ Inputs come from two places:
 
 Decisions are **deterministic given a calibration file**: no timing runs
 at plan time, so CI plans with a fake calibration table and gets
-reproducible buckets.
+reproducible buckets.  Only a finite memory budget (a device that
+reports its limit) adds inputs: each bucket's compiled footprint and
+the bytes the device holds when the bucket is planned.
 
 >>> cal = CostCalibration(flops_per_s=1e9, bytes_per_s=1e9,
 ...                       dispatch_s=1e-3, psum_latency_s=5e-3,
@@ -167,6 +169,24 @@ def _best_of(thunk, reps: int = 3) -> float:
     return best
 
 
+def device_memory_budget() -> float:
+    """Bytes the first local device may hold (``bytes_limit`` of its
+    ``memory_stats()``), or ``inf`` where the backend reports no limit
+    (the CPU).  The budget the memory gate of :meth:`CostModel.decide`
+    holds a stacked bucket to."""
+    stats = jax.devices()[0].memory_stats() or {}
+    limit = stats.get("bytes_limit")
+    return float(limit) if limit else math.inf
+
+
+def device_bytes_in_use() -> float:
+    """Bytes the first local device holds now (0 where the backend
+    reports none): arrays that stay resident while a bucket runs, such as
+    the model being quantized."""
+    stats = jax.devices()[0].memory_stats() or {}
+    return float(stats.get("bytes_in_use", 0))
+
+
 def calibrate(mesh=None, *, path: str | None = None,
               force: bool = False) -> CostCalibration:
     """One-time per-host microbenchmark; cached to ``path`` (default
@@ -175,7 +195,8 @@ def calibrate(mesh=None, *, path: str | None = None,
 
     Measures: dense matmul throughput, streaming memory bandwidth,
     per-dispatch overhead, and (when ``mesh`` spans >1 device) psum
-    latency + bandwidth solved from two payload sizes.  Wall cost is a
+    latency + bandwidth solved from two payload sizes.  The memory budget
+    is the device's own limit (:func:`device_memory_budget`).  Wall cost is a
     few hundred ms; ``force=True`` re-measures."""
     import jax.numpy as jnp
 
@@ -208,7 +229,6 @@ def calibrate(mesh=None, *, path: str | None = None,
     shard_efficiency = 1.0
     n_devices = 1
     if mesh is not None and math.prod(mesh.shape.values()) > 1:
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         axis = mesh.axis_names[0]
@@ -216,7 +236,7 @@ def calibrate(mesh=None, *, path: str | None = None,
 
         def timed_psum(side: int) -> float:
             x = jnp.zeros((side, side), jnp.float32)
-            fn = jax.jit(shard_map(
+            fn = jax.jit(jax.shard_map(
                 lambda v: jax.lax.psum(v, axis), mesh=mesh,
                 in_specs=P(None, None), out_specs=P(None, None)))
             jax.block_until_ready(fn(x))
@@ -232,9 +252,9 @@ def calibrate(mesh=None, *, path: str | None = None,
         # aggregate speedup of column-sharding a matmul over this mesh:
         # ~k when the shards are real chips, ~1 when they share one host
         w = jax.random.normal(wkey, (1024, 2048), jnp.float32)
-        sh = jax.jit(shard_map(lambda v: v @ v.T @ v, mesh=mesh,
-                               in_specs=P(None, axis),
-                               out_specs=P(None, axis)))
+        sh = jax.jit(jax.shard_map(lambda v: v @ v.T @ v, mesh=mesh,
+                                   in_specs=P(None, axis),
+                                   out_specs=P(None, axis)))
         rep = jax.jit(lambda v: v @ v.T @ v)
         jax.block_until_ready(sh(w))
         jax.block_until_ready(rep(w))
@@ -248,6 +268,7 @@ def calibrate(mesh=None, *, path: str | None = None,
         dispatch_s=dispatch_s, psum_latency_s=psum_latency_s,
         psum_bytes_per_s=psum_bytes_per_s,
         shard_efficiency=shard_efficiency,
+        memory_budget_bytes=device_memory_budget(),
         backend=jax.default_backend(), jax_version=jax.__version__,
         n_devices=n_devices, source="measured")
     try:
@@ -269,19 +290,54 @@ def analytic_layer_costs(method: str, m: int, n: int, rank: int,
     return flops, bytes_
 
 
+def _layer_args(spec, L: int | None = None):
+    """Abstract ``(W, H or None, key)`` of one layer of ``spec``, or of
+    ``L`` stacked layers."""
+    import jax.numpy as jnp
+    lead = () if L is None else (L,)
+    W = jax.ShapeDtypeStruct(lead + (spec.m, spec.n), jnp.float32)
+    H = (jax.ShapeDtypeStruct(lead + (spec.m, spec.m), jnp.float32)
+         if spec.has_gram else None)
+    return W, H, jax.ShapeDtypeStruct(lead + (2,), jnp.uint32)
+
+
+def compiled_bucket_footprint(spec, L: int) -> float | None:
+    """Device bytes the fused ``L``-layer bucket of ``spec`` holds at
+    once: the arguments, outputs and temporaries of its stacked program,
+    compiled for the default backend (``memory_analysis()``).  It is the
+    very program :func:`~repro.core.batched.run_bucket` runs if the
+    bucket fuses, so a persistent compile cache serves that second
+    compile.  ``inf`` where the compiler finds the program does not fit
+    the device; ``None`` for a geometry-only spec (nothing to lower) or a
+    backend that reports no analysis."""
+    from repro.core.batched import BucketSpec, requeue_spec, run_bucket
+
+    if not isinstance(spec, BucketSpec):
+        return None
+    Ws, Hs, keys = _layer_args(spec, L)
+    try:
+        compiled = run_bucket.lower(Ws, Hs, keys,
+                                    spec=requeue_spec(spec)).compile()
+    except jax.errors.JaxRuntimeError as e:
+        if "RESOURCE_EXHAUSTED" in str(e):
+            return math.inf
+        raise
+    mem = compiled.memory_analysis()
+    if mem is None:
+        return None
+    return float(mem.argument_size_in_bytes + mem.output_size_in_bytes
+                 + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+
+
 def xla_layer_costs(spec) -> tuple[float, float]:
     """Per-layer FLOP/byte counts from XLA's lowered ``cost_analysis()``
     of the bucket's actual traced core (no compile, no execution) — the
     same counter ``launch/dryrun.py`` reports, read through
     :func:`normalize_cost_analysis`."""
-    import jax.numpy as jnp
-
     from repro.core.batched import quantize_single
 
-    W = jax.ShapeDtypeStruct((spec.m, spec.n), jnp.float32)
-    key = jax.ShapeDtypeStruct((2,), jnp.uint32)
+    W, H, key = _layer_args(spec)
     if spec.has_gram:
-        H = jax.ShapeDtypeStruct((spec.m, spec.m), jnp.float32)
         lowered = jax.jit(
             lambda w, h, k: quantize_single(w, h, k, spec)).lower(W, H, key)
     else:
@@ -305,7 +361,8 @@ class CostModel:
     ``layer_costs`` maps a :class:`~repro.core.batched.BucketSpec`-like
     object (needs ``.m .n .method .rank .has_gram``) to per-layer
     ``(flops, bytes)``; defaults to :func:`xla_layer_costs` with the
-    analytic fallback.  All decisions are pure arithmetic over the
+    analytic fallback.  Under a finite memory budget the gate also reads
+    :meth:`bucket_footprint`.  All decisions are pure arithmetic over the
     calibration table — no timing, deterministic."""
 
     def __init__(self, calibration: CostCalibration, *,
@@ -313,6 +370,7 @@ class CostModel:
         self.calibration = calibration
         self._layer_costs = layer_costs or xla_layer_costs
         self._cost_cache: dict = {}
+        self._footprint_cache: dict = {}
 
     @classmethod
     def coerce(cls, obj) -> "CostModel | None":
@@ -331,12 +389,26 @@ class CostModel:
             return cls(cal)
         raise TypeError(f"cannot coerce {type(obj).__name__} to CostModel")
 
+    @staticmethod
+    def _key(spec) -> tuple:
+        return (spec.method, spec.m, spec.n, spec.rank, spec.has_gram,
+                getattr(spec, "bits", None), getattr(spec, "group_size", None))
+
     def layer_costs(self, spec) -> tuple[float, float]:
-        k = (spec.method, spec.m, spec.n, spec.rank, spec.has_gram,
-             getattr(spec, "bits", None), getattr(spec, "group_size", None))
+        k = self._key(spec)
         if k not in self._cost_cache:
             self._cost_cache[k] = self._layer_costs(spec)
         return self._cost_cache[k]
+
+    def bucket_footprint(self, spec, L: int) -> float:
+        """:func:`compiled_bucket_footprint`, or ``L`` times the layer's
+        ``bytes`` where that gives ``None``."""
+        k = self._key(spec) + (L,)
+        if k not in self._footprint_cache:
+            fp = compiled_bucket_footprint(spec, L)
+            self._footprint_cache[k] = (L * self.layer_costs(spec)[1]
+                                        if fp is None else fp)
+        return self._footprint_cache[k]
 
     def path_times(self, spec, L: int, k: int) -> dict:
         """Predicted seconds per candidate path for an ``L``-layer bucket
@@ -372,11 +444,13 @@ class CostModel:
 
     def decide(self, spec, L: int, k: int) -> tuple[str, int]:
         """Choose ``(exec_path, n_shards)`` for one bucket from predicted
-        time.  The stacked working set is gated against the calibration's
-        memory budget first — a bucket that cannot hold ``L`` stacked
+        time.  The stacked working set (:meth:`bucket_footprint`) is
+        gated first against the calibration's memory budget less what the
+        device already holds — a bucket that cannot hold ``L`` stacked
         layers runs sequentially regardless of predicted speed."""
-        _, by = self.layer_costs(spec)
-        if L * by > self.calibration.memory_budget_bytes:
+        budget = self.calibration.memory_budget_bytes
+        if math.isfinite(budget) and self.bucket_footprint(spec, L) > (
+                budget - device_bytes_in_use()):
             return "sequential", 1
         times = self.path_times(spec, L, k)
         best = min(EXEC_PATHS, key=lambda p: times.get(p, math.inf))

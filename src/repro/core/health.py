@@ -215,6 +215,9 @@ def _check_one(W: Array, leaves: dict, spec: BucketSpec):
     return finite, err, jnp.sum(R * R)
 
 
+_check_one_jit = jax.jit(_check_one, static_argnums=(2,))
+
+
 @partial(jax.jit, static_argnames=("spec",))
 def _check_bucket_jit(Ws: Array, leaves: dict, spec: BucketSpec):
     return jax.vmap(lambda W, lv: _check_one(W, lv, spec))(Ws, leaves)
@@ -226,7 +229,13 @@ def check_bucket(Ws: Array, leaves: dict, spec: BucketSpec,
     clean.  One compiled executable per bucket signature (same jit-cache
     discipline as :func:`repro.core.batched.run_bucket`); the
     blowup-factor comparison happens on the host so the policy is not
-    baked into the executable."""
+    baked into the executable.  A bucket that ran down the sequential
+    path is checked slice by slice, as it ran (``Ws`` is then a list)."""
+    if spec.exec_path == "sequential":
+        return np.array([
+            check_single(W, {k: v[j] for k, v in leaves.items()}, spec,
+                         policy)
+            for j, W in enumerate(Ws)], bool)
     finite, err, rerr = _check_bucket_jit(Ws, leaves, spec)
     finite = np.asarray(finite)
     err = np.asarray(err, np.float64)
@@ -240,8 +249,7 @@ def check_single(W: Array, leaves: dict, spec: BucketSpec,
                  policy: HealthPolicy) -> bool:
     """Single-slice instance of :func:`check_bucket` (the sequential
     engine's per-layer guard — identical criterion, identical math)."""
-    finite, err, rerr = jax.jit(
-        _check_one, static_argnums=(2,))(W, leaves, spec)
+    finite, err, rerr = _check_one_jit(W, leaves, spec)
     err = float(err)
     return bool(finite) and np.isfinite(err) and \
         err <= policy.blowup_factor * float(rerr) + policy.abs_tol

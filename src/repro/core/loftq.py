@@ -19,6 +19,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.core.linalg import sym_topr
 from repro.core.quantizer import (QuantConfig, dequantize_int, dequantize_nf4,
                                   quantize_int, quantize_nf4)
 
@@ -40,20 +41,23 @@ def svd_lowrank_topr(dW_local: Array, rank: int, axis: str | None = None):
     ``R = I``:
 
         G = dW dW^T          -- psum over ``axis`` when given (m x m)
-        eigh(G) -> U, S^2    -- replicated across shards
+        top-r eigh(G) -> U, S^2   -- replicated across shards
         V_local = dW_l^T U S^{-1}   -- shard-local
 
     Returns ``(U (m, r), S (r,), V_local (n_local, r))`` with ``U``/``S``
     identical on every shard.  Safe under both ``shard_map`` (the psum is
     the only communication) and ``vmap`` (the batched engine maps it over a
     stacked ``(L, m, n_local)`` bucket — the psum reduces an ``(L, m, m)``
-    stack in one collective)."""
+    stack in one collective).  Without ``axis`` every column is at hand,
+    and a tall ``dW`` (``n < m``) is factored through the
+    smaller ``(n, n)`` Gram of its transpose."""
+    if axis is None and dW_local.shape[1] < dW_local.shape[0]:
+        V, S, U = svd_lowrank_topr(dW_local.T, rank)
+        return U, S, V
     G = dW_local @ dW_local.T
     if axis is not None:
         G = jax.lax.psum(G, axis)
-    evals, evecs = jnp.linalg.eigh(G)                   # ascending
-    top = evals[::-1][:rank]
-    U = evecs[:, ::-1][:, :rank]
+    top, U = sym_topr(G, rank)
     S = jnp.sqrt(jnp.maximum(top, 1e-30))
     V_l = (dW_local.T @ U) / S[None, :]                 # (n_local, r)
     return U, S, V_l

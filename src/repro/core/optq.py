@@ -27,6 +27,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.core.linalg import cholesky_lower, tri_inv_lower
 from repro.core.quantizer import QuantConfig, quant_params, stable_round
 
 Array = jax.Array
@@ -40,13 +41,13 @@ def dampen(H: Array, lambda_frac: float) -> Array:
 
 def inv_cholesky_upper(H: Array) -> Array:
     """Upper-triangular U with H^{-1} = U^T @ U (torch ``cholesky(upper=True)``
-    of the inverse — the factor GPTQ's sweep consumes row-by-row)."""
-    m = H.shape[0]
-    L = jnp.linalg.cholesky(H)
-    eye = jnp.eye(m, dtype=H.dtype)
-    Linv = jax.scipy.linalg.solve_triangular(L, eye, lower=True)
-    Hinv = Linv.T @ Linv
-    return jnp.linalg.cholesky(Hinv).T
+    of the inverse — the factor GPTQ's sweep consumes row-by-row).
+
+    With ``J`` the order reversal, ``Lr = chol(J H J)`` gives
+    ``H = (J Lr J)(J Lr J)^T`` with ``J Lr J`` upper-triangular, so
+    ``U = J Lr^{-1} J``: one Cholesky and one triangular inverse, and no
+    explicit ``H^{-1}``."""
+    return tri_inv_lower(cholesky_lower(H[::-1, ::-1]))[::-1, ::-1]
 
 
 @partial(jax.jit, static_argnames=("bits", "block_size", "act_order"))
@@ -198,7 +199,6 @@ def optq_quantize_sharded(W: Array, H: Array, cfg: QuantConfig, mesh,
     with every leaf except ``H`` column-sharded over ``axis``.
     """
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     W = jnp.asarray(W, jnp.float32)
     H = jnp.asarray(H, jnp.float32)
@@ -210,7 +210,7 @@ def optq_quantize_sharded(W: Array, H: Array, cfg: QuantConfig, mesh,
         return optq_quantize_core(Wl, H_, cfg)
 
     col = P(None, axis)
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(col, P(None, None)),
-                   out_specs=(col, col, col, col))
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(col, P(None, None)),
+                       out_specs=(col, col, col, col))
     return fn(W, H)

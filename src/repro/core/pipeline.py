@@ -87,7 +87,8 @@ from repro.obs import metrics as obs_metrics
 from repro.obs import names as obs_names
 from repro.obs import trace as obs_trace
 from repro.models.transformer import ModelConfig, forward
-from repro.utils import GramStore, capture_grams, get_path, set_path, tree_paths
+from repro.utils import (GramStore, capture_grams, get_path, set_path,
+                         solver_precision, tree_paths)
 
 Array = jax.Array
 
@@ -240,6 +241,7 @@ def _shared_base_dequant(newlin: dict, m: int, qspec: QSpec) -> Array:
                           qspec.group_size)
 
 
+@solver_precision()     # f32 solver products, as the batched core
 def _quantize_one(W: Array, H: Array | None, qspec: QSpec, method: str,
                   key: Array):
     """Quantize one (m, n) weight. Returns dict of new leaves."""
@@ -588,7 +590,8 @@ def _coerce_recipe(recipe: QuantRecipe | None, method: str | None,
                               qspec or cfg.quant or QSpec())
 
 
-def quantize_model(params: dict, cfg: ModelConfig, calib_batches: list[dict],
+def quantize_model(params: dict, cfg: ModelConfig,
+                   calib_batches: "list[dict] | GramStore",
                    *, recipe: QuantRecipe | None = None,
                    method: str | None = None, qspec: QSpec | None = None,
                    seed: int = 0, engine: str = "batched",
@@ -653,7 +656,11 @@ def quantize_model(params: dict, cfg: ModelConfig, calib_batches: list[dict],
     sites keep their dense ``w`` leaf — as do sites the health ladder
     degraded to dense; ``linear_apply`` dequantizes each quantized site
     from its own stored shapes, so mixed bit-widths need no per-site
-    config at apply time."""
+    config at apply time.
+
+    ``calib_batches`` may instead be the populated
+    :class:`~repro.utils.GramStore` a previous call returned: two engines
+    then quantize against the same Grams without calibrating twice."""
     if engine not in _ENGINES:
         raise ValueError(f"unknown engine {engine!r}; options "
                          f"{tuple(_ENGINES)}")
@@ -680,10 +687,13 @@ def quantize_model(params: dict, cfg: ModelConfig, calib_batches: list[dict],
     eparams = to_eager_params(params, cfg)
     sites = recipe.resolve(quantizable_linear_paths(eparams))
     _check_scan_uniform(sites, cfg)
-    with obs_trace.span("quant.calibrate", batches=len(calib_batches)):
-        # grams land host-side (device_get in GramStore.add): no fence
-        store = run_calibration(eparams, cfg, calib_batches,
-                                report=report)
+    if isinstance(calib_batches, GramStore):
+        store = calib_batches
+    else:
+        with obs_trace.span("quant.calibrate", batches=len(calib_batches)):
+            # grams land host-side (device_get in GramStore.add): no fence
+            store = run_calibration(eparams, cfg, calib_batches,
+                                    report=report)
     new_params = jax.tree.map(lambda a: a, eparams)   # structural copy
     extra = ({"cost_model": cost_model, "compile_cache": compile_cache}
              if engine == "batched" else {})

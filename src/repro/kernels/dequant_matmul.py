@@ -7,8 +7,9 @@ Packed uint8 words stream HBM->VMEM at bits/8 bytes per weight — the whole
 point of the paper's deployment; unpacking is a VPU shift/mask on an int32
 upcast, group scales/zeros broadcast across their 64-row groups, and the
 dequantized bf16 tile feeds the MXU.  Block shapes default to MXU-aligned
-(bm, bk, bn) = (128, 256, 128); bk is constrained to a multiple of the
-group size so scale tiles align with weight tiles.
+(bm, bk, bn) = (128, 512, 128); bk is a multiple of eight groups (or all
+of K), so the (bk / g, bn) scale and zero tiles meet the TPU's
+eight-sublane block rule and align with the weight tiles.
 
 The fused-LoRA variant accumulates x@A (bm x r) in a second scratch during
 the same K sweep and adds (x@A)@B^T on the final K step — one pass over x
@@ -23,10 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across releases; accept both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
-
 Array = jax.Array
 
 
@@ -40,6 +37,20 @@ def _unpack_tile(words: Array, bits: int) -> Array:
     parts = [(w32 >> (bits * j)) & mask for j in range(per)]
     stacked = jnp.stack(parts, axis=1)            # (bk/pack, per, bn)
     return stacked.reshape(words.shape[0] * per, words.shape[1])
+
+
+def _k_block(K: int, g: int, want: int) -> int:
+    """K tile: the largest multiple of ``8 * g`` that divides ``K`` and is
+    at most ``max(want, 8 * g)``, else all of ``K``.  A scale/zero block
+    then holds a multiple of eight group rows or the whole array, which
+    Mosaic requires of a block's second-to-last dimension."""
+    step = 8 * g
+    b = (max(want, step) // step) * step
+    while b >= step:
+        if K % b == 0:
+            return b
+        b -= step
+    return K
 
 
 def _dequant_tile(words: Array, s: Array, z: Array, bits: int,
@@ -72,7 +83,7 @@ def _kernel(x_ref, w_ref, s_ref, z_ref, o_ref, acc, *, bits, group, nk):
                                              "bk", "interpret"))
 def dequant_matmul(x: Array, packed: Array, scales: Array, zeros: Array, *,
                    bits: int, group_size: int, bm: int = 128, bn: int = 128,
-                   bk: int = 256, interpret: bool = True) -> Array:
+                   bk: int = 512, interpret: bool) -> Array:
     """y = x @ dequant(packed).  x (..., K); packed (K*bits/8, N)."""
     orig_shape = x.shape
     K = x.shape[-1]
@@ -83,8 +94,7 @@ def dequant_matmul(x: Array, packed: Array, scales: Array, zeros: Array, *,
     pack = 8 // bits if bits in (2, 4) else 1
     bm = min(bm, M)
     bn = min(bn, N)
-    bk = min(bk, K)
-    bk = max((bk // g) * g, g) if g <= bk else K   # align to groups
+    bk = _k_block(K, g, bk)
     nk = K // bk
 
     grid = (M // bm, N // bn, nk)
@@ -100,7 +110,7 @@ def dequant_matmul(x: Array, packed: Array, scales: Array, zeros: Array, *,
         out_specs=pl.BlockSpec((bm, bn), lambda m, n, k: (m, n)),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x2, packed, scales, zeros)
@@ -134,7 +144,7 @@ def _kernel_lora(x_ref, w_ref, s_ref, z_ref, a_ref, b_ref, o_ref, acc, xa,
 def dequant_matmul_lora(x: Array, packed: Array, scales: Array, zeros: Array,
                         lora_a: Array, lora_b: Array, *, bits: int,
                         group_size: int, bm: int = 128, bn: int = 128,
-                        bk: int = 256, interpret: bool = True) -> Array:
+                        bk: int = 512, interpret: bool) -> Array:
     """Fused y = x @ Wq + (x @ A) @ B^T — one sweep over x."""
     orig_shape = x.shape
     K = x.shape[-1]
@@ -143,8 +153,7 @@ def dequant_matmul_lora(x: Array, packed: Array, scales: Array, zeros: Array,
     r = lora_a.shape[1]
     g = K if group_size is None else group_size
     pack = 8 // bits if bits in (2, 4) else 1
-    bm, bn, bk = min(bm, M), min(bn, N), min(bk, K)
-    bk = max((bk // g) * g, g) if g <= bk else K
+    bm, bn, bk = min(bm, M), min(bn, N), _k_block(K, g, bk)
     nk = K // bk
 
     grid = (M // bm, N // bn, nk)
@@ -163,7 +172,7 @@ def dequant_matmul_lora(x: Array, packed: Array, scales: Array, zeros: Array,
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32),
                         pltpu.VMEM((bm, r), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x2, packed, scales, zeros, lora_a, lora_b)

@@ -2,16 +2,16 @@
 
 Online-softmax blocking (Dao et al., adapted to TPU): grid
 (B*Hq, Sq/bq, Sk/bk) with the key loop innermost; running max m, running
-sum l, and the (bq x d) output accumulator live in VMEM scratch.  Causal
-blocks above the diagonal are masked; fully-masked key blocks still execute
-(Pallas grids are static) but contribute nothing — the ops.py wrapper notes
-the ~2x theoretical win a lower-triangular grid would add on real TPU.
+sum l, and the (bq x d) output accumulator live in VMEM scratch.  Key
+blocks above the causal diagonal, or past a sequence's length, skip their
+compute; the grid is static, so they are still visited.
 
 GQA: the q-head grid index maps to kv head q_head // (Hq // Hkv) via the
 BlockSpec index_map — no repeated K/V materialization.
 
 ``lengths`` (B,) adds per-sequence key masking: keys at ``kpos >=
-lengths[b]`` are dropped for every query of sequence ``b``.  This is the
+lengths[b]`` are dropped for every query of sequence ``b`` (without
+``lengths``, every sequence holds all ``Sk`` keys).  This is the
 serving integration point — the paged-KV decode path hands the kernel each
 request's token count so one batch can mix requests at different progress.
 Every sequence must have length >= 1 (an all-masked first block would make
@@ -27,22 +27,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across releases; accept both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
-
 Array = jax.Array
 NEG_INF = -1e30
 
 
-def _kernel(*refs, scale, causal, bq, bk, nk, has_lengths):
-    if has_lengths:
-        q_ref, k_ref, v_ref, len_ref, o_ref, m_scr, l_scr, acc = refs
-    else:
-        q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc = refs
-        len_ref = None
+def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc, *,
+            scale, causal, bq, bk, nk, hq):
     kb = pl.program_id(2)
     qb = pl.program_id(1)
+    length = len_ref[pl.program_id(0) // hq]      # scalar-prefetched (SMEM)
 
     @pl.when(kb == 0)
     def _init():
@@ -50,12 +43,13 @@ def _kernel(*refs, scale, causal, bq, bk, nk, has_lengths):
         l_scr[...] = jnp.zeros_like(l_scr)
         acc[...] = jnp.zeros_like(acc)
 
-    run = True
+    # key blocks past the sequence's length contribute nothing; with
+    # causal masking, neither do blocks strictly above the diagonal band
+    run = kb * bk < length
     if causal:
-        # key block strictly above the diagonal band contributes nothing
-        run = (kb * bk) <= (qb * bq + bq - 1)
+        run = run & ((kb * bk) <= (qb * bq + bq - 1))
 
-    @pl.when(run if causal else True)
+    @pl.when(run)
     def _step():
         q = q_ref[0].astype(jnp.float32) * scale            # (bq, d)
         k = k_ref[0].astype(jnp.float32)                    # (bk, d)
@@ -64,8 +58,7 @@ def _kernel(*refs, scale, causal, bq, bk, nk, has_lengths):
         if causal:
             qpos = qb * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
             s = jnp.where(kpos <= qpos, s, NEG_INF)
-        if len_ref is not None:
-            s = jnp.where(kpos < len_ref[0, 0], s, NEG_INF)
+        s = jnp.where(kpos < length, s, NEG_INF)
         m_prev = m_scr[...]                                  # (bq, 1)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
@@ -78,7 +71,8 @@ def _kernel(*refs, scale, causal, bq, bk, nk, has_lengths):
 
     @pl.when(kb == nk - 1)
     def _done():
-        o_ref[0] = (acc[...] / jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
+        o_ref[0] = (acc[...] / jnp.maximum(l_scr[...], 1e-30)
+                    ).astype(o_ref.dtype)
 
 
 def _block(size: int, want: int) -> int:
@@ -90,15 +84,17 @@ def _block(size: int, want: int) -> int:
     return b
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "bq", "bk", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("causal", "bq", "bk", "interpret"))
 def flash_attention(q: Array, k: Array, v: Array, *, causal: bool = True,
-                    bq: int = 128, bk: int = 128, interpret: bool = True,
+                    bq: int = 128, bk: int = 128, interpret: bool,
                     lengths: Array | None = None) -> Array:
     """q (B, Hq, Sq, d); k/v (B, Hkv, Sk, d) -> (B, Hq, Sq, d).
 
     ``lengths`` (B,) int32: optional per-sequence valid key count (keys at
     ``kpos >= lengths[b]`` are masked for all of b's queries); must be
-    >= 1 everywhere."""
+    >= 1 everywhere.  It reaches the kernel through scalar prefetch
+    (SMEM), so the index maps and the mask read it without a VMEM block."""
     B, Hq, Sq, d = q.shape
     Hkv, Sk = k.shape[1], k.shape[2]
     rep = Hq // Hkv
@@ -106,38 +102,36 @@ def flash_attention(q: Array, k: Array, v: Array, *, causal: bool = True,
     bk = _block(Sk, bk)
     nq, nk = Sq // bq, Sk // bk
     scale = 1.0 / (d ** 0.5)
+    if lengths is None:
+        lengths = jnp.full((B,), Sk, jnp.int32)
 
     q4 = q.reshape(B * Hq, Sq, d)
     k4 = k.reshape(B * Hkv, Sk, d)
     v4 = v.reshape(B * Hkv, Sk, d)
 
-    def kv_map(h, qb, kb):
+    def kv_map(h, qb, kb, lens):
         return (h // rep, kb, 0)
 
-    in_specs = [
-        pl.BlockSpec((1, bq, d), lambda h, qb, kb: (h, qb, 0)),
-        pl.BlockSpec((1, bk, d), kv_map),
-        pl.BlockSpec((1, bk, d), kv_map),
-    ]
-    operands = [q4, k4, v4]
-    if lengths is not None:
-        lens = jnp.broadcast_to(lengths[:, None].astype(jnp.int32),
-                                (B, Hq)).reshape(B * Hq, 1)
-        in_specs.append(pl.BlockSpec((1, 1), lambda h, qb, kb: (h, 0)))
-        operands.append(lens)
+    def q_map(h, qb, kb, lens):
+        return (h, qb, 0)
 
-    out = pl.pallas_call(
-        functools.partial(_kernel, scale=scale, causal=causal, bq=bq, bk=bk,
-                          nk=nk, has_lengths=lengths is not None),
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(B * Hq, nq, nk),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, bq, d), lambda h, qb, kb: (h, qb, 0)),
-        out_shape=jax.ShapeDtypeStruct((B * Hq, Sq, d), q.dtype),
+        in_specs=[pl.BlockSpec((1, bq, d), q_map),
+                  pl.BlockSpec((1, bk, d), kv_map),
+                  pl.BlockSpec((1, bk, d), kv_map)],
+        out_specs=pl.BlockSpec((1, bq, d), q_map),
         scratch_shapes=[pltpu.VMEM((bq, 1), jnp.float32),
                         pltpu.VMEM((bq, 1), jnp.float32),
-                        pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+                        pltpu.VMEM((bq, d), jnp.float32)])
+    out = pl.pallas_call(
+        functools.partial(_kernel, scale=scale, causal=causal, bq=bq, bk=bk,
+                          nk=nk, hq=Hq),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B * Hq, Sq, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(*operands)
+    )(lengths.astype(jnp.int32), q4, k4, v4)
     return out.reshape(B, Hq, Sq, d)

@@ -14,10 +14,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across releases; accept both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
-
 Array = jax.Array
 
 
@@ -39,7 +35,7 @@ def _kernel(xi_ref, xj_ref, o_ref, acc, *, nt):
 
 @functools.partial(jax.jit, static_argnames=("bi", "bj", "bt", "interpret"))
 def gram(x: Array, *, bi: int = 128, bj: int = 128, bt: int = 512,
-         interpret: bool = True) -> Array:
+         interpret: bool) -> Array:
     """H = X^T X.  x (..., D) flattened over leading dims."""
     D = x.shape[-1]
     x2 = x.reshape(-1, D)
@@ -57,7 +53,7 @@ def gram(x: Array, *, bi: int = 128, bj: int = 128, bt: int = 512,
         out_specs=pl.BlockSpec((bi, bj), lambda i, j, t: (i, j)),
         out_shape=jax.ShapeDtypeStruct((D, D), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bi, bj), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x2, x2)

@@ -34,19 +34,24 @@ def gram_ref(x: Array) -> Array:
     return x32.T @ x32
 
 
-def flash_attention_ref(q: Array, k: Array, v: Array, *, causal: bool = True
-                        ) -> Array:
-    """q (B, Hq, S, d); k/v (B, Hkv, S, d); GQA by head grouping; softmax f32."""
-    B, Hq, S, d = q.shape
-    Hkv = k.shape[1]
+def flash_attention_ref(q: Array, k: Array, v: Array, *, causal: bool = True,
+                        lengths: Array | None = None) -> Array:
+    """q (B, Hq, Sq, d); k/v (B, Hkv, Sk, d); GQA by head grouping; softmax
+    f32.  ``causal`` masks key ``j`` for query ``i`` when ``j > i``;
+    ``lengths`` (B,) masks keys at ``j >= lengths[b]``."""
+    B, Hq, Sq, d = q.shape
+    Hkv, Sk = k.shape[1], k.shape[2]
     rep = Hq // Hkv
     kk = jnp.repeat(k, rep, axis=1)
     vv = jnp.repeat(v, rep, axis=1)
     logits = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
                         kk.astype(jnp.float32)) / jnp.sqrt(d)
+    kpos = jnp.arange(Sk)[None, None, None, :]
     if causal:
-        mask = jnp.tril(jnp.ones((S, S), bool))
-        logits = jnp.where(mask[None, None], logits, -1e30)
+        logits = jnp.where(kpos <= jnp.arange(Sq)[None, None, :, None],
+                           logits, -1e30)
+    if lengths is not None:
+        logits = jnp.where(kpos < lengths[:, None, None, None], logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bhqk,bhkd->bhqd", probs, vv.astype(jnp.float32))
     return out.astype(q.dtype)

@@ -7,19 +7,29 @@ one device).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape: tuple, axes: tuple):
+    """``jax.make_mesh`` with every axis ``Auto``: the partitioner places
+    intermediates from the in/out shardings, which the pjit train step and
+    the shard_map quantize engine rely on (``make_mesh`` now defaults to
+    ``Explicit`` axes, which make every sharding part of an array's type)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_local_mesh(n_data: int = 1, n_model: int = 1, n_pod: int | None = None):
     """Small mesh over however many (possibly fake) devices exist."""
     if n_pod:
-        return jax.make_mesh((n_pod, n_data, n_model), ("pod", "data", "model"))
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+        return _auto_mesh((n_pod, n_data, n_model), ("pod", "data", "model"))
+    return _auto_mesh((n_data, n_model), ("data", "model"))
 
 
 def make_model_mesh(n_model: int | None = None):
@@ -29,7 +39,7 @@ def make_model_mesh(n_model: int | None = None):
     so ``quantize_model(..., mesh=make_model_mesh())`` puts every local
     device on the model axis.  ``n_model`` defaults to all local devices."""
     n = n_model or len(jax.devices())
-    return jax.make_mesh((n,), ("model",))
+    return _auto_mesh((n,), ("model",))
 
 
 def data_axes_of(mesh) -> tuple:

@@ -30,6 +30,7 @@ from repro.obs import names as obs_names
 from repro.core.pipeline import quantize_model
 from repro.core.recipe import QuantRecipe, load_plan
 from repro.data import DataConfig, TokenStream
+from repro.launch.jax_cache import enable_compilation_cache
 from repro.launch.steps import make_decode_step
 from repro.models.modules import QSpec
 from repro.models.parallel import LOCAL
@@ -160,6 +161,7 @@ def _serve_legacy(args, cfg, params) -> int:
 
 
 def main(argv=None) -> int:
+    enable_compilation_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--arch", required=True)
     p.add_argument("--smoke", action="store_true")

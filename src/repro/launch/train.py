@@ -38,6 +38,7 @@ from repro.core.pipeline import (allocate_plan, quantization_manifest,
                                  quantize_model)
 from repro.core.recipe import QuantRecipe, load_plan
 from repro.data import DataConfig, TokenStream
+from repro.launch.jax_cache import enable_compilation_cache
 from repro.launch.steps import build_state, make_train_step
 from repro.models.modules import QSpec
 from repro.models.parallel import LOCAL
@@ -112,6 +113,7 @@ def parse_args(argv=None):
 
 
 def main(argv=None) -> int:
+    enable_compilation_cache()
     args = parse_args(argv)
     metrics_out = args.metrics_out or (
         obs.default_metrics_path("train") if args.trace_out else "")

@@ -182,9 +182,9 @@ def attn_decode(p, cfg: AttnConfig, x: Array, cache: dict, *,
         V = jax.lax.dynamic_update_slice(
             cache["v"], v.astype(cache["v"].dtype), (0, slot, 0, 0))
     if qspec is not None and qspec.use_kernel and not cfg.sliding_window:
-        from repro.kernels.flash_attention import flash_attention
+        from repro.kernels import ops as kops
         counts = (idx + 1) if vec else jnp.full((B,), idx + 1)
-        out = flash_attention(
+        out = kops.flash_attention(
             q.transpose(0, 2, 1, 3), K.transpose(0, 2, 1, 3),
             V.transpose(0, 2, 1, 3), causal=False,
             lengths=counts.astype(jnp.int32)).transpose(0, 2, 1, 3)

@@ -172,7 +172,6 @@ def moe_apply(p: dict, cfg: MoEConfig, x: Array, *,
         return y.reshape(B, S, D), aux
 
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     mesh = pctx.mesh
     dp, mp = pctx.data_axes, pctx.model_axis
@@ -200,9 +199,9 @@ def moe_apply(p: dict, cfg: MoEConfig, x: Array, *,
         aux = jax.lax.pmean(aux, dp)
         return y, aux
 
-    fn = shard_map(local_fn, mesh=mesh,
-                   in_specs=(P(None, None), ew_specs, P(dp, None)),
-                   out_specs=(P(dp, None), P()),
-                   check_rep=False)
+    fn = jax.shard_map(local_fn, mesh=mesh,
+                       in_specs=(P(None, None), ew_specs, P(dp, None)),
+                       out_specs=(P(dp, None), P()),
+                       check_vma=False)
     y, aux = fn(p["router"]["w"], {k: p[k] for k in ("gate", "up", "down")}, xt)
     return y.reshape(B, S, D), aux

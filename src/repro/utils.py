@@ -45,6 +45,16 @@ def current_scope() -> str:
 # ---------------------------------------------------------------------------
 
 
+def solver_precision():
+    """Context (or decorator) that makes every f32 matmul traced inside
+    it a full f32 product (``"highest"``).  A TPU otherwise runs an f32
+    matmul as one bf16 pass, which perturbs a calibration Gram or a
+    solver product by ~2^-8 relative: enough for the damped Cholesky of
+    an ill-conditioned Gram to fail.  The CPU always multiplies in f32,
+    so it sees no change."""
+    return jax.default_matmul_precision("highest")
+
+
 class GramStore:
     """Accumulates per-layer Gram matrices H = sum_batches X^T X (f32).
 
@@ -59,11 +69,13 @@ class GramStore:
         if keep_leading:
             x3 = jnp.asarray(x, jnp.float32)
             x3 = x3.reshape(x3.shape[0], -1, x3.shape[-1])
-            h = jax.device_get(jnp.einsum("ecd,ecf->edf", x3, x3))
+            with solver_precision():
+                h = jax.device_get(jnp.einsum("ecd,ecf->edf", x3, x3))
             cnt = x3.shape[1]
         else:
             x2 = jnp.asarray(x, jnp.float32).reshape(-1, x.shape[-1])
-            h = np.asarray(x2.T @ x2)
+            with solver_precision():
+                h = np.asarray(x2.T @ x2)
             cnt = x2.shape[0]
         if path in self.grams:
             self.grams[path] = self.grams[path] + h

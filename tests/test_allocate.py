@@ -253,7 +253,8 @@ def test_sweep_sharded_matches_local():
             tasks.append(LayerTask(f"{method}{i}", None, W,
                                    jnp.asarray(X.T @ X),
                                    jax.random.PRNGKey(i), site=spec))
-    mesh = jax.make_mesh((2,), ("model",))
+    from repro.launch.mesh import make_model_mesh
+    mesh = make_model_mesh(2)
     specs = list(plan_buckets(tasks, mesh=mesh, for_eval=True))
     assert all(s.n_shards == 2 for s in specs), specs
     local = evaluate_layer_batch(tasks)

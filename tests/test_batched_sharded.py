@@ -10,6 +10,7 @@ import numpy as np
 import pytest
 
 from repro.core.batched import bucket_shards, make_spec
+from repro.launch.mesh import make_model_mesh
 from repro.models.modules import QSpec
 from tests.util import parity_prelude, run_with_devices
 
@@ -41,7 +42,8 @@ def test_sharded_bucket_parity_and_fallback():
         from repro.core.pipeline import _quantize_one
         from repro.models.modules import QSpec
 
-        mesh = jax.make_mesh((2,), ("model",))
+        from repro.launch.mesh import make_model_mesh
+        mesh = make_model_mesh(2)
         rng = np.random.default_rng(0)
         qspec = QSpec(bits=2, group_size=16, rank=8)
 
@@ -140,7 +142,8 @@ def test_sharded_site_lora_matches_unsharded():
         Hs = jnp.asarray(np.stack([
             (lambda X: X.T @ X)(rng.normal(size=(128, m)).astype(np.float32))
             for _ in range(S)]))
-        mesh = jax.make_mesh((2,), ("model",))
+        from repro.launch.mesh import make_model_mesh
+        mesh = make_model_mesh(2)
 
         A0, B0 = cloq_site_lora(Hs, dW, r)
         A1, B1 = cloq_site_lora(Hs, dW, r, mesh=mesh)
@@ -162,7 +165,7 @@ def test_sequential_engine_rejects_mesh():
                       dtype=jnp.float32)
     params = init_params(jax.random.PRNGKey(0), cfg)
     ds = TokenStream(DataConfig(vocab=128, seq_len=16, global_batch=2))
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_model_mesh(1)
     with pytest.raises(ValueError, match="batched"):
         quantize_model(params, cfg, [ds.next_batch()],
                        engine="sequential", mesh=mesh)

@@ -1,10 +1,12 @@
 """Theorem 3.1 and CLoQ-core properties (the paper's central math)."""
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from tests._hypothesis_compat import given, settings, st
 
-from repro.core.cloq import (cloq_init, discrepancy_norms, gram_root,
-                             lowrank_objective, regularize_gram, split_factors)
+from repro.core.cloq import (chol_root, cloq_init, cloq_lowrank_local,
+                             discrepancy_norms, gram_root, lowrank_objective,
+                             regularize_gram, split_factors)
 from repro.core.magr import magr_preprocess, project_l1_ball, prox_linf
 from repro.core.optq import optq_quantize, gram_error
 from repro.core.quantizer import QuantConfig, rtn
@@ -82,6 +84,27 @@ def test_rank_deficient_gram_pseudoinverse_path():
     A, B = cloq_init(H, dW, 4)
     assert bool(jnp.all(jnp.isfinite(A))) and bool(jnp.all(jnp.isfinite(B)))
     assert lowrank_objective(H, dW, A, B) <= gram_error(H, dW) + 1e-3
+
+
+@pytest.mark.parametrize("m,t", [(32, 12), (256, 64)])
+def test_rank_deficient_gram_cholesky_root(m, t):
+    """The TPU's Cholesky root (run here on the CPU) keeps the remark's
+    contract: finite adapters within 1e-3 of the eigen root's objective
+    on an unregularized, rank-deficient H, and the same product on a
+    regularized one."""
+    rng = np.random.default_rng(3)
+    W = jnp.asarray(rng.normal(size=(m, m // 2)), jnp.float32)
+    X = jnp.asarray(rng.normal(size=(t, m)), jnp.float32)
+    dW = W - rtn(W, QuantConfig(bits=2, group_size=16))
+    for H in (X.T @ X, regularize_gram(X.T @ X)):
+        R, Rinv = chol_root(H)
+        A, B = cloq_lowrank_local(R, Rinv, dW, 4)
+        assert bool(jnp.all(jnp.isfinite(A))) and bool(jnp.all(jnp.isfinite(B)))
+        A_e, B_e = cloq_init(H, dW, 4)
+        ref = lowrank_objective(H, dW, A_e, B_e)
+        assert lowrank_objective(H, dW, A, B) <= ref * (1 + 1e-3) + 1e-4
+    prod, want = np.asarray(A @ B.T), np.asarray(A_e @ B_e.T)
+    assert np.linalg.norm(prod - want) / np.linalg.norm(want) < 1e-2
 
 
 def test_discrepancy_cloq_below_rtn_and_loftq():

@@ -34,7 +34,8 @@ def test_pjit_train_step_matches_local():
         for b in batches: st, m_ref = f(st, b)
 
         # 2x4 mesh pjit
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_local_mesh
+        mesh = make_local_mesh(2, 4)
         pctx = pcontext_for(mesh)
         st2 = build_state(p, ocfg)
         specs = state_pspecs(st2, mesh)
@@ -60,7 +61,8 @@ def test_moe_shard_map_matches_local():
         p = moe_init(jax.random.PRNGKey(0), cfg, dtype=jnp.float32)
         x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, 32))
         y_ref, aux_ref = moe_apply(p, cfg, x)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_local_mesh
+        mesh = make_local_mesh(2, 4)
         y, aux = moe_apply(p, cfg, x, pctx=pcontext_for(mesh))
         np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
                                    atol=2e-5)
@@ -83,7 +85,8 @@ def test_distributed_optq_and_cloq_match_local():
         X = jnp.asarray(rng.normal(size=(512, m)), jnp.float32)
         H = X.T @ X
         cfg = QuantConfig(bits=4, group_size=16)
-        mesh = jax.make_mesh((8,), ("model",))
+        from repro.launch.mesh import make_model_mesh
+        mesh = make_model_mesh(8)
         Q1, C1, s, z = optq_quantize(W, H, cfg)
         Q2, C2, _, _ = optq_quantize_sharded(W, H, cfg, mesh)
         np.testing.assert_allclose(np.asarray(Q1), np.asarray(Q2), atol=2e-4)
@@ -101,9 +104,9 @@ def test_int8_ef_psum():
     run_with_devices("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         from repro.optim import ef_psum_int8
-        mesh = jax.make_mesh((8,), ("data",))
+        from repro.launch.mesh import make_local_mesh
+        mesh = make_local_mesh(8, 1)
         g = jax.random.normal(jax.random.PRNGKey(0), (8, 64))
 
         def f(g_local, res):
@@ -111,9 +114,9 @@ def test_int8_ef_psum():
                                            "data")
             return synced["g"], new_res["g"][None]
 
-        fn = shard_map(f, mesh=mesh, in_specs=(P("data", None), P("data", None)),
-                       out_specs=(P(None), P("data", None)),
-                       check_rep=False)
+        fn = jax.shard_map(f, mesh=mesh, in_specs=(P("data", None), P("data", None)),
+                           out_specs=(P(None), P("data", None)),
+                           check_vma=False)
         res0 = jnp.zeros((8, 64))
         synced, res1 = fn(g, res0)
         true_mean = jnp.mean(g, axis=0)
@@ -131,13 +134,14 @@ def test_checkpoint_reshard_across_meshes():
         import jax, jax.numpy as jnp, numpy as np, tempfile
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.checkpoint import save_tree, restore_tree
-        mesh1 = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_local_mesh
+        mesh1 = make_local_mesh(2, 4)
         w = jnp.arange(64 * 32, dtype=jnp.float32).reshape(64, 32)
         sharded = jax.device_put(w, NamedSharding(mesh1, P(None, "model")))
         d = tempfile.mkdtemp()
         save_tree({"w": sharded}, d, 1)
         # restore onto a DIFFERENT mesh shape (elastic restart)
-        mesh2 = jax.make_mesh((4, 2), ("data", "model"))
+        mesh2 = make_local_mesh(4, 2)
         sh = {"w": NamedSharding(mesh2, P("model", None))}
         tree, meta = restore_tree(d, shardings=sh)
         assert tree["w"].sharding.is_equivalent_to(sh["w"], 2)

@@ -265,7 +265,8 @@ def test_sharded_engine_fault_heal_parity():
         from repro.models.transformer import ModelConfig, init_params
         from repro.utils import tree_paths
 
-        mesh = jax.make_mesh((2,), ("model",))
+        from repro.launch.mesh import make_model_mesh
+        mesh = make_model_mesh(2)
         cfg = ModelConfig(name="t", family="dense", n_layers=2, d_model=32,
                           vocab=128, n_heads=4, n_kv_heads=2, d_ff=64,
                           dtype=jnp.float32, scan_layers=False)

@@ -116,7 +116,8 @@ from repro.core.pipeline import _quantize_one
 from repro.models.modules import QSpec
 
 method = __METHOD__
-mesh = jax.make_mesh((2,), ("model",))
+from repro.launch.mesh import make_model_mesh
+mesh = make_model_mesh(2)
 qspec = QSpec(bits=2, group_size=16, rank=8)
 for shape in SHAPES:
     tasks = make_tasks(shape)

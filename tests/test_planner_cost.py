@@ -27,6 +27,7 @@ from repro.core.batched import (LayerTask, plan_buckets, plan_manifest,
 from repro.core.compile_cache import CompileCache, PersistedFunction
 from repro.core.costmodel import (CostCalibration, CostModel,
                                   load_calibration)
+from repro.launch.mesh import make_model_mesh
 from repro.models.modules import QSpec
 from tests.util import run_with_devices
 
@@ -74,6 +75,44 @@ def test_memory_gate_forces_sequential():
     cm = _model(memory_budget_bytes=1024.0)
     path, shards = cm.decide_geometry("cloq", m=256, n=256, L=64, k=2)
     assert (path, shards) == ("sequential", 1)
+
+
+def test_memory_gate_reads_compiled_footprint():
+    """Under a finite budget the gate holds the fused bucket's compiled
+    footprint (arguments + outputs + temporaries of the stacked program)
+    to it, not the layers' traffic."""
+    from repro.core.costmodel import compiled_bucket_footprint
+    tasks = _toy_tasks(32, 48, 1)
+    spec = next(iter(plan_buckets(tasks, QSpec(bits=4, group_size=16,
+                                               rank=4), "cloq")))
+    L = 8
+    fp = compiled_bucket_footprint(spec, L)
+    assert fp >= 4.0 * L * (32 * 48 + 32 * 32)      # W and H at least
+    assert compiled_bucket_footprint(_geo("cloq", 32, 48), L) is None
+    for budget, path in ((fp - 1.0, "sequential"), (fp + 1.0, "replicated")):
+        cm = CostModel(CostCalibration(**FAKE, memory_budget_bytes=budget),
+                       layer_costs=lambda s: (1.0, 1e30))
+        assert cm.decide(spec, L, 1) == (path, 1)
+
+
+def test_bucket_the_compiler_cannot_fit_is_infinite(monkeypatch):
+    """A TPU compiler refuses a stacked program larger than HBM: that
+    bucket's footprint is infinite, so the gate runs it sequentially."""
+    import repro.core.batched as batched
+    from repro.core.costmodel import compiled_bucket_footprint
+
+    class _Refused:
+        def lower(self, *a, **k):
+            raise jax.errors.JaxRuntimeError(
+                "RESOURCE_EXHAUSTED: Ran out of memory in memory space hbm")
+    monkeypatch.setattr(batched, "run_bucket", _Refused())
+    spec = next(iter(plan_buckets(_toy_tasks(32, 48, 1),
+                                  QSpec(bits=4, group_size=16, rank=4),
+                                  "cloq")))
+    assert compiled_bucket_footprint(spec, 8) == float("inf")
+    cm = CostModel(CostCalibration(**FAKE, memory_budget_bytes=1e30),
+                   layer_costs=lambda s: (1.0, 1.0))
+    assert cm.decide(spec, 8, 1) == ("sequential", 1)
 
 
 def test_indivisible_width_never_shards():
@@ -141,7 +180,8 @@ def test_plan_buckets_cost_model_on_mesh():
                               psum_bytes_per_s=1e8, shard_efficiency=2.0)
         cm = CostModel(cal, layer_costs=lambda s: (8.0 * s.m * s.m * s.n,
                                                    4.0 * s.m * s.n))
-        mesh = jax.make_mesh((2,), ("model",))
+        from repro.launch.mesh import make_model_mesh
+        mesh = make_model_mesh(2)
         qspec = QSpec(bits=2, group_size=64, rank=16)
 
         def plan(m, n, L):
@@ -190,7 +230,7 @@ def test_manifest_divergence_single_warning():
     for b in manifest["buckets"]:
         b["spec"]["n_shards"] = 2
         b["spec"]["exec_path"] = "sharded"
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_model_mesh(1)
     with warnings.catch_warnings(record=True) as rec:
         warnings.simplefilter("always")
         shardings = manifest_shardings(manifest, mesh)
@@ -206,7 +246,7 @@ def test_manifest_same_layout_no_warning():
     from repro.checkpoint.manager import manifest_shardings
 
     manifest = _manifest()
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_model_mesh(1)
     with warnings.catch_warnings(record=True) as rec:
         warnings.simplefilter("always")
         manifest_shardings(manifest, mesh)
@@ -223,7 +263,7 @@ def test_manifest_cost_model_replay():
     cm = _model()
     buckets = plan_buckets(tasks, qspec, "cloq", cost_model=cm)
     manifest = plan_manifest(tasks, buckets)
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_model_mesh(1)
     with warnings.catch_warnings(record=True) as rec:
         warnings.simplefilter("always")
         manifest_shardings(manifest, mesh, cost_model=cm)
@@ -258,7 +298,8 @@ def test_manifest_roundtrip_other_device_count():
         manifest = plan_manifest(tasks, plan_buckets(tasks, qspec, "cloq"))
         assert all(b["spec"]["n_shards"] == 1 for b in manifest["buckets"])
 
-        mesh = jax.make_mesh((4,), ("model",))
+        from repro.launch.mesh import make_model_mesh
+        mesh = make_model_mesh(4)
         with warnings.catch_warnings(record=True) as rec:
             warnings.simplefilter("always")
             shardings = manifest_shardings(manifest, mesh)
