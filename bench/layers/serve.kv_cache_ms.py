@@ -1,0 +1,16 @@
+"""Paged KV cache traffic (``serve/kv_cache``: gather of each row's pages,
+extraction of the token just written, scatter back to the pool): device
+time of the ops under the ``kv_cache`` scope, per traced engine step, in
+ms.  Moves ``itl_p95_ms``.  Nothing to read where no op carries the
+scope."""
+from bench import program_trace
+
+SCOPE = "kv_cache"
+
+
+def read(run):
+    if run["job"] != "serve" or not run["steps"]:
+        return None
+    tr = program_trace.current()
+    secs = tr.scope_seconds.get(SCOPE, 0.0) if tr else 0.0
+    return 1e3 * secs / len(run["steps"]) if secs > 0 else None

@@ -143,8 +143,8 @@ def _obs_overhead_row(rng, m: int = 256, n: int = 256,
     """Observability overhead on a quantize bucket: the same
     ``quantize_layer_batch`` call with the span tracer disabled (the
     default — every ``obs.trace.span`` returns the shared no-op span)
-    vs enabled with sync fencing (``REPRO_TRACE_SYNC`` semantics, the
-    worst case: every span close blocks on its registered arrays).
+    vs enabled (every span recorded and entering its profiler
+    annotation).
     ``check_bench.py`` gates ``overhead_pct`` — tracing must stay cheap
     enough to leave on for any diagnostic run.  ``noop_span_ns`` is the
     per-call cost of a disabled span, the price every instrumented
@@ -169,7 +169,7 @@ def _obs_overhead_row(rng, m: int = 256, n: int = 256,
     quant()                                # compile before timing
     obs_trace.disable()
     t_off = _best_of(quant, reps=5)
-    obs_trace.enable(sync=True)
+    obs_trace.enable()
     try:
         t_on = _best_of(quant, reps=5)
     finally:
@@ -183,7 +183,7 @@ def _obs_overhead_row(rng, m: int = 256, n: int = 256,
             pass
     noop_ns = (time.perf_counter() - t0) / reps * 1e9
     return {"m": m, "n": n, "n_layers": n_layers,
-            "untraced_s": round(t_off, 3), "traced_sync_s": round(t_on, 3),
+            "untraced_s": round(t_off, 3), "traced_s": round(t_on, 3),
             "overhead_pct": round((t_on - t_off) / t_off * 100, 2),
             "noop_span_ns": round(noop_ns, 1)}
 
@@ -549,7 +549,7 @@ def run() -> dict:
 
     ob = _obs_overhead_row(rng)
     print(f"  obs tracing {ob['m']}x{ob['n']} x{ob['n_layers']}: "
-          f"off={ob['untraced_s']}s on={ob['traced_sync_s']}s "
+          f"off={ob['untraced_s']}s on={ob['traced_s']}s "
           f"({ob['overhead_pct']}% overhead, "
           f"noop span {ob['noop_span_ns']}ns)", flush=True)
 

@@ -878,9 +878,8 @@ def quantize_layer_batch(tasks: list[LayerTask], qspec=None,
                             path=("sharded" if spec.n_shards > 1
                                   else spec.exec_path),
                             shards=spec.n_shards,
-                            layers=len(idxs)) as sp:
+                            layers=len(idxs)):
             idxs, out = dispatch(b, cur)         # async dispatch
-            sp.sync(out)    # REPRO_TRACE_SYNC=1: fence before span close
         staged = None
         if stream and b + 1 < len(items) and (b + 1) not in loaded:
             # double-buffer: stage bucket b+1 on the host while the device
@@ -892,9 +891,8 @@ def quantize_layer_batch(tasks: list[LayerTask], qspec=None,
             results[i] = {k: v[j] for k, v in out.items()}
         if guarded:
             with obs_trace.span("bucket.health_check", bucket=b,
-                                layers=len(idxs)) as hsp:
+                                layers=len(idxs)):
                 ok = health.check_bucket(cur[0], out, spec, policy)
-                hsp.sync(ok)
             report.checked += len(idxs)
             obs_metrics.counter(obs_names.HEALTH_CHECKED).inc(len(idxs))
             for j, i in enumerate(idxs):
@@ -970,9 +968,8 @@ def evaluate_layer_batch(tasks: list[LayerTask],
         if staged is None:
             staged = _stage_bucket(tasks, items[b][1], items[b][0])
         with obs_trace.span("sweep.execute", bucket=b,
-                            candidates=len(items[b][1])) as sp:
+                            candidates=len(items[b][1])):
             idxs, out = dispatch(b, staged)      # async dispatch
-            sp.sync(out)
         staged = None
         if stream and b + 1 < len(items):
             staged = _stage_bucket(tasks, items[b + 1][1], items[b + 1][0])

@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.linalg import cholesky_lower, tri_inv_lower
+from repro.utils import scope
 
 Array = jax.Array
 
@@ -60,6 +61,7 @@ def chol_root(H: Array):
     return L.T, tri_inv_lower(L).T
 
 
+@scope("cloq")
 def gram_root(H: Array, eps: float = 1e-10):
     """A root R with H = R^T R, plus its inverse.
 
@@ -124,6 +126,7 @@ def discrepancy_norms(H: Array, Q: Array, A: Array, B: Array, W: Array):
     return fro, spec
 
 
+@scope("cloq")
 def cloq_lowrank_local(R: Array, Rinv: Array, dW_local: Array, rank: int,
                        split: str = "paper", axis: str | None = None):
     """Shard-local body of the Gram-trick CLoQ solve.

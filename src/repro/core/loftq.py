@@ -22,6 +22,7 @@ import jax.numpy as jnp
 from repro.core.linalg import sym_topr
 from repro.core.quantizer import (QuantConfig, dequantize_int, dequantize_nf4,
                                   quantize_int, quantize_nf4)
+from repro.utils import scope
 
 Array = jax.Array
 
@@ -63,6 +64,7 @@ def svd_lowrank_topr(dW_local: Array, rank: int, axis: str | None = None):
     return U, S, V_l
 
 
+@scope("loftq")
 def loftq_init(W: Array, cfg: QuantConfig, rank: int, iters: int = 5,
                axis: str | None = None):
     """Returns (Q_dequant, A, B, qstate) after ``iters`` AltMin rounds.

@@ -29,6 +29,7 @@ import jax.numpy as jnp
 
 from repro.core.linalg import cholesky_lower, tri_inv_lower
 from repro.core.quantizer import QuantConfig, quant_params, stable_round
+from repro.utils import scope
 
 Array = jax.Array
 
@@ -128,6 +129,7 @@ def pick_block(m: int, block_size: int) -> int:
     return m
 
 
+@scope("optq")
 def optq_quantize_core(W: Array, H: Array, cfg: QuantConfig,
                        scales: Array | None = None,
                        zeros: Array | None = None):

@@ -698,12 +698,11 @@ def quantize_model(params: dict, cfg: ModelConfig,
     extra = ({"cost_model": cost_model, "compile_cache": compile_cache}
              if engine == "batched" else {})
     with obs_trace.span("quant.model", engine=engine,
-                        sites=len(sites)) as sp:
+                        sites=len(sites)):
         _ENGINES[engine](eparams, store, sites, seed, cfg, new_params,
                          progress, mesh, shard_axis, policy=policy,
                          report=report, journal=journal,
                          should_stop=should_stop, **extra)
-        sp.sync(new_params)
     if journal_dir is not None:
         report.save(os.path.join(journal_dir, "health.json"))
     new_cfg = dataclasses.replace(cfg, quant=recipe.qspec)

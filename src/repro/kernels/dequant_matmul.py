@@ -113,6 +113,7 @@ def dequant_matmul(x: Array, packed: Array, scales: Array, zeros: Array, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="dequant_matmul",
     )(x2, packed, scales, zeros)
     return out.reshape(*orig_shape[:-1], N)
 
@@ -175,5 +176,6 @@ def dequant_matmul_lora(x: Array, packed: Array, scales: Array, zeros: Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="dequant_matmul_lora",
     )(x2, packed, scales, zeros, lora_a, lora_b)
     return out.reshape(*orig_shape[:-1], N)

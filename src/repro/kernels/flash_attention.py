@@ -133,5 +133,6 @@ def flash_attention(q: Array, k: Array, v: Array, *, causal: bool = True,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_attention",
     )(lengths.astype(jnp.int32), q4, k4, v4)
     return out.reshape(B, Hq, Sq, d)

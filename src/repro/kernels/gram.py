@@ -56,4 +56,5 @@ def gram(x: Array, *, bi: int = 128, bj: int = 128, bt: int = 512,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="gram",
     )(x2, x2)

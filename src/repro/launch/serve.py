@@ -199,7 +199,8 @@ def main(argv=None) -> int:
     p.add_argument("--trace-out", default="", metavar="FILE",
                    help="write a chrome-trace/Perfetto span timeline "
                         "(quantize buckets + serve steps/decodes) to FILE; "
-                        "REPRO_TRACE_SYNC=1 fences async dispatch")
+                        "the spans also land in any profiler trace taken "
+                        "meanwhile")
     p.add_argument("--metrics-out", default="", metavar="FILE",
                    help="write the metrics-registry snapshot to FILE "
                         "(defaults to results/metrics-serve.json when "

@@ -103,8 +103,8 @@ def parse_args(argv=None):
     p.add_argument("--trace-out", default="", metavar="FILE",
                    help="write a chrome-trace/Perfetto span timeline of "
                         "the run to FILE (load at https://ui.perfetto.dev; "
-                        "REPRO_TRACE_SYNC=1 fences async dispatch at span "
-                        "close)")
+                        "the spans also land in any profiler trace taken "
+                        "meanwhile)")
     p.add_argument("--metrics-out", default="", metavar="FILE",
                    help="write the metrics-registry snapshot to FILE "
                         "(defaults to results/metrics-train.json when "

@@ -14,7 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.quantizer import dequantize_int, unpack_codes
-from repro.utils import current_scope, record_activation
+from repro.utils import current_scope, record_activation, scope
 
 Array = jax.Array
 
@@ -78,15 +78,17 @@ def linear_apply(p: dict, x: Array, qspec: QSpec | None = None) -> Array:
     model quantized with a heterogeneous :class:`repro.core.recipe.
     QuantRecipe` — 2-bit MLPs next to 4-bit attention — runs with the one
     global ``qspec`` only gating the Pallas kernel path."""
+    # the calibration key is read above; the scopes below only name ops
     record_activation(current_scope(), x)
     m = x.shape[-1]
     if "qcodes" in p:
         assert qspec is not None, "quantized params need a QSpec"
         if "absmax" in p:                      # NF4 (QLoRA baseline)
             from repro.core.quantizer import dequantize_nf4
-            codes = unpack_codes(p["qcodes"], 4, m)
-            w = dequantize_nf4(codes, p["absmax"], _group_of(p["absmax"], m),
-                               x.dtype)
+            with scope("dequant"):
+                codes = unpack_codes(p["qcodes"], 4, m)
+                w = dequantize_nf4(codes, p["absmax"],
+                                   _group_of(p["absmax"], m), x.dtype)
             y = x @ w
         else:
             bits = packed_bits(p["qcodes"].shape[-2], m)
@@ -97,22 +99,25 @@ def linear_apply(p: dict, x: Array, qspec: QSpec | None = None) -> Array:
                                         p["zeros"], bits=bits,
                                         group_size=group)
             else:
-                codes = unpack_codes(p["qcodes"], bits, m)
-                w = dequantize_int(codes, p["scales"], p["zeros"],
-                                   group, dtype=x.dtype)
+                with scope("dequant"):
+                    codes = unpack_codes(p["qcodes"], bits, m)
+                    w = dequantize_int(codes, p["scales"], p["zeros"],
+                                       group, dtype=x.dtype)
                 y = x @ w
     else:
         y = x @ p["w"].astype(x.dtype)
     if "lora_a" in p:
-        a = p["lora_a"].astype(x.dtype)
-        b = p["lora_b"].astype(x.dtype)
-        if a.ndim == 3:
-            # per-request adapters (serving): a (B, m, r), b (B, n, r) —
-            # one gathered einsum over the whole batch, never a row loop
-            y = y + jnp.einsum("bsr,bnr->bsn",
-                               jnp.einsum("bsm,bmr->bsr", x, a), b)
-        else:
-            y = y + (x @ a) @ b.T
+        with scope("lora"):
+            a = p["lora_a"].astype(x.dtype)
+            b = p["lora_b"].astype(x.dtype)
+            if a.ndim == 3:
+                # per-request adapters (serving): a (B, m, r), b (B, n, r)
+                # — one gathered einsum over the whole batch, never a row
+                # loop
+                y = y + jnp.einsum("bsr,bnr->bsn",
+                                   jnp.einsum("bsm,bmr->bsr", x, a), b)
+            else:
+                y = y + (x @ a) @ b.T
     if "b" in p:
         y = y + p["b"].astype(y.dtype)
     return y
@@ -148,12 +153,14 @@ def embedding_init(key, vocab: int, d: int, dtype=jnp.bfloat16) -> dict:
 
 
 def embedding_apply(p: dict, tokens: Array) -> Array:
-    return jnp.take(p["w"], tokens, axis=0)
+    with scope("embed"):
+        return jnp.take(p["w"], tokens, axis=0)
 
 
 def lm_head_apply(p: dict, x: Array) -> Array:
     """Logits. ``p`` may be a tied embedding ({'w': (V, d)}) or a linear."""
-    w = p["w"].astype(x.dtype)
-    if w.shape[0] != x.shape[-1]:          # tied embedding (V, d)
-        return x @ w.T
-    return x @ w
+    with scope("lm_head"):
+        w = p["w"].astype(x.dtype)
+        if w.shape[0] != x.shape[-1]:          # tied embedding (V, d)
+            return x @ w.T
+        return x @ w

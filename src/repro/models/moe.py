@@ -70,26 +70,32 @@ def _expert_matmul(pd: dict, buf: Array, qspec: QSpec | None) -> Array:
         if "absmax" in pd:                     # NF4 (QLoRA baseline)
             from repro.core.quantizer import dequantize_nf4
             group = m // pd["absmax"].shape[-2]
-            codes = jax.vmap(lambda c: unpack_codes(c, 4, m))(pd["qcodes"])
-            w = jax.vmap(lambda c, a: dequantize_nf4(
-                c, a, group, dtype=buf.dtype))(codes, pd["absmax"])
+            with scope("dequant"):
+                codes = jax.vmap(lambda c: unpack_codes(c, 4, m))(
+                    pd["qcodes"])
+                w = jax.vmap(lambda c, a: dequantize_nf4(
+                    c, a, group, dtype=buf.dtype))(codes, pd["absmax"])
         else:
             # bits/group derived from the stored shapes (per-site recipes
             # may quantize expert stacks differently; see modules.packed_bits)
             from repro.models.modules import packed_bits
             bits = packed_bits(pd["qcodes"].shape[-2], m)
             group = m // pd["scales"].shape[-2]
-            codes = jax.vmap(lambda c: unpack_codes(c, bits, m))(pd["qcodes"])
-            w = jax.vmap(lambda c, s, z: dequantize_int(
-                c, s, z, group, dtype=buf.dtype))(
-                    codes, pd["scales"], pd["zeros"])
+            with scope("dequant"):
+                codes = jax.vmap(lambda c: unpack_codes(c, bits, m))(
+                    pd["qcodes"])
+                w = jax.vmap(lambda c, s, z: dequantize_int(
+                    c, s, z, group, dtype=buf.dtype))(
+                        codes, pd["scales"], pd["zeros"])
     else:
         w = pd["w"].astype(buf.dtype)
     y = jnp.einsum("ecm,emn->ecn", buf, w)
     if "lora_a" in pd:
-        a = pd["lora_a"].astype(buf.dtype)
-        b = pd["lora_b"].astype(buf.dtype)
-        y = y + jnp.einsum("ecr,enr->ecn", jnp.einsum("ecm,emr->ecr", buf, a), b)
+        with scope("lora"):
+            a = pd["lora_a"].astype(buf.dtype)
+            b = pd["lora_b"].astype(buf.dtype)
+            y = y + jnp.einsum("ecr,enr->ecn",
+                               jnp.einsum("ecm,emr->ecr", buf, a), b)
     return y
 
 

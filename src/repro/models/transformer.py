@@ -554,20 +554,27 @@ def decode_step(params: dict, cfg: ModelConfig, cache: dict, tokens: Array, *,
         def body(carry, inp):
             x = carry
             bp, k_l, v_l, extras = inp
-            h = rmsnorm_apply(bp["ln1"], x)
-            y, new_kv = attn_decode(bp["attn"], acfg, h,
-                                    {"k": k_l, "v": v_l, "idx": idx}, qspec=q)
-            x = x + y
+            with scope("attn"):
+                h = rmsnorm_apply(bp["ln1"], x)
+                y, new_kv = attn_decode(bp["attn"], acfg, h,
+                                        {"k": k_l, "v": v_l, "idx": idx},
+                                        qspec=q)
+                x = x + y
             if cfg.family == "encdec":
                 cp = extras
-                x = x + cross_attn_apply(cp["xattn"], cfg.attn_cfg(causal=False),
-                                         rmsnorm_apply(cp["ln"], x),
-                                         cache["enc_out"], qspec=q)
-            h2 = rmsnorm_apply(bp["ln2"], x)
+                with scope("cross"):
+                    x = x + cross_attn_apply(
+                        cp["xattn"], cfg.attn_cfg(causal=False),
+                        rmsnorm_apply(cp["ln"], x), cache["enc_out"], qspec=q)
             if cfg.family == "moe":
-                y2, _ = moe_apply(bp["moe"], cfg.moe_cfg(), h2, qspec=q, pctx=pctx)
+                with scope("moe"):
+                    y2, _ = moe_apply(bp["moe"], cfg.moe_cfg(),
+                                      rmsnorm_apply(bp["ln2"], x), qspec=q,
+                                      pctx=pctx)
             else:
-                y2 = swiglu_apply(bp["mlp"], h2, q)
+                with scope("mlp"):
+                    y2 = swiglu_apply(bp["mlp"], rmsnorm_apply(bp["ln2"], x),
+                                      q)
             return x + y2, (new_kv["k"], new_kv["v"])
 
         blocks = params["blocks" if cfg.family != "encdec" else "dec_blocks"]
@@ -597,10 +604,11 @@ def decode_step(params: dict, cfg: ModelConfig, cache: dict, tokens: Array, *,
         def body(carry, inp):
             x = carry
             bp, cx_l, cb_l, st_l = inp
-            h = rmsnorm_apply(bp["norm"], x)
-            y, nc = mamba_decode(bp["mamba"], scfg, h,
-                                 {"conv_x": cx_l, "conv_bc": cb_l,
-                                  "state": st_l}, qspec=q)
+            with scope("mamba"):
+                h = rmsnorm_apply(bp["norm"], x)
+                y, nc = mamba_decode(bp["mamba"], scfg, h,
+                                     {"conv_x": cx_l, "conv_bc": cb_l,
+                                      "state": st_l}, qspec=q)
             x = x + y
             return x, (nc["conv_x"], nc["conv_bc"], nc["state"])
 

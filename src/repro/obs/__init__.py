@@ -34,11 +34,11 @@ def default_metrics_path(tool: str) -> str:
 
 
 @contextlib.contextmanager
-def session(trace_out=None, metrics_out=None, *, sync=None):
+def session(trace_out=None, metrics_out=None):
     """Enable tracing when ``trace_out`` is set, and on exit (even an
     exceptional one) export the trace and/or metrics snapshot."""
     if trace_out:
-        trace.enable(sync=sync)
+        trace.enable()
     try:
         yield
     finally:

@@ -51,6 +51,7 @@ SERVE_SUBMITTED = "serve.requests_submitted"
 SERVE_ADMITTED = "serve.requests_admitted"
 SERVE_FINISHED = "serve.requests_finished"
 SERVE_TOKENS = "serve.tokens"
+SERVE_PROMPT_ROWS = "serve.prompt_rows"   # decode rows that fed a prompt token
 SERVE_STEPS = "serve.steps"
 SERVE_KV_PAGES_IN_USE = "serve.kv_pages_in_use"
 SERVE_KV_PAGES_TOTAL = "serve.kv_pages_total"
@@ -64,6 +65,26 @@ SERVE_KV_OCCUPANCY = "serve.kv_occupancy"
 TRAIN_STEPS = "train.steps"
 TRAIN_STEP_TIME = "train.step_s"
 
+# -- layer scopes ----------------------------------------------------------
+
+# Names ``repro.utils.scope`` enters around each layer kind, projection and
+# solver; ``jax.named_scope`` carries them into every op's ``op_name`` /
+# ``tf_op``, where a profiler-trace reader attributes device time to them.
+# Dynamic path parts (``blocks.<i>``, ``sites.<i>``) are not listed.
+SCOPES = (
+    # layer kinds
+    "embed", "attn", "mlp", "moe", "mamba", "cross", "shared", "lm_head",
+    # projections
+    "q", "k", "v", "o", "gate", "up", "down",
+    "z_proj", "x_proj", "bc_proj", "dt_proj", "out_proj",
+    # inside a linear: XLA dequant of a packed base, the adapter product
+    "dequant", "lora",
+    # serving: paged KV cache traffic, per-row adapter gather
+    "kv_cache", "lora_gather",
+    # quantization: calibration Gram and the solvers
+    "gram", "cloq", "optq", "loftq",
+)
+
 # -- declarations ----------------------------------------------------------
 
 COUNTERS = (
@@ -76,7 +97,7 @@ COUNTERS = (
     JOURNAL_RESTORED, JOURNAL_COMMITTED, JOURNAL_SKIPPED_TASKS,
     CKPT_SAVES, CKPT_RESTORES,
     SERVE_SUBMITTED, SERVE_ADMITTED, SERVE_FINISHED,
-    SERVE_TOKENS, SERVE_STEPS,
+    SERVE_TOKENS, SERVE_PROMPT_ROWS, SERVE_STEPS,
     TRAIN_STEPS,
 )
 
@@ -90,7 +111,7 @@ _LATENCY_EDGES = (0.0005, 0.001, 0.003, 0.01, 0.03, 0.1,
 _FRACTION_EDGES = (0.1, 0.2, 0.3, 0.4, 0.5, 0.6, 0.7, 0.8, 0.9, 1.0)
 
 HISTOGRAMS = {
-    SERVE_TTFT: _LATENCY_EDGES,
+    SERVE_TTFT: _LATENCY_EDGES + (30.0, 100.0),
     SERVE_TOKEN_LATENCY: _LATENCY_EDGES,
     SERVE_QUEUE_WAIT: _LATENCY_EDGES,
     SERVE_KV_OCCUPANCY: _FRACTION_EDGES,

@@ -1,17 +1,22 @@
-"""Zero-dependency span tracer with chrome-trace (Perfetto) export.
+"""Span tracer on the profiler's clock, with chrome-trace (Perfetto) export.
 
 Spans are context managers (or the :func:`traced` decorator) recording
 wall-clock intervals with attributes, process id and thread id.  The
 module-level tracer is **disabled by default** and every ``span()`` call
 then returns a shared no-op singleton — one function call plus a bool
-check, nothing allocated, so instrumented hot paths cost effectively
-nothing when tracing is off (gated by ``obs_overhead_row`` in table10).
+check, nothing allocated and nothing imported, so instrumented hot paths
+cost effectively nothing when tracing is off (gated by
+``obs_overhead_row`` in table10).
 
-JAX dispatch is asynchronous: a span that closes right after a jitted
-call has measured *dispatch*, not compute.  When ``REPRO_TRACE_SYNC=1``
-(or ``enable(sync=True)``), arrays registered via ``span.sync(tree)``
-are ``jax.block_until_ready``-fenced at span close, *before* the end
-timestamp is read, so the span brackets the device work.
+While the tracer is enabled, each span also enters a
+``jax.profiler.TraceAnnotation`` of its name and attributes, so when a
+profiler trace is being taken the span lands on its host plane, on the
+same clock as the device's ops.  JAX dispatch is asynchronous: a span
+that closes right after a jitted call has measured *dispatch*; the device
+trace beside it shows when the work ran.  Phases that cross calls (a
+request's queueing, prefill, generation) are recorded with
+:meth:`Tracer.complete` from timestamps the caller took with
+``time.perf_counter``; they stay in the span list only.
 
 Export is the chrome-trace JSON array format (``{"traceEvents": [...]}``
 with ``"X"`` complete events, microsecond timestamps) — load the file at
@@ -38,8 +43,6 @@ import os
 import threading
 import time
 
-SYNC_ENV = "REPRO_TRACE_SYNC"
-
 
 class _NullSpan:
     """Shared do-nothing span returned while tracing is disabled."""
@@ -54,9 +57,6 @@ class _NullSpan:
     def set(self, **attrs):
         return self
 
-    def sync(self, tree):
-        return tree
-
 
 _NULL_SPAN = _NullSpan()
 
@@ -67,43 +67,38 @@ def _jsonable(v):
     return str(v)
 
 
+def _annotation(name: str, args: dict):
+    """The profiler's host-side marker for one span (recorded only while a
+    profiler trace is being taken)."""
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation(name, **args)
+
+
 class Span:
     """One live span; record happens at ``__exit__``."""
-    __slots__ = ("_tracer", "name", "args", "_t0", "_pending")
+    __slots__ = ("_tracer", "name", "args", "_t0", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, args: dict):
         self._tracer = tracer
         self.name = name
         self.args = args
         self._t0 = 0.0
-        self._pending = None
+        self._ann = None
 
     def set(self, **attrs) -> "Span":
         """Attach attributes mid-span (shown under *args* in Perfetto)."""
         self.args.update(attrs)
         return self
 
-    def sync(self, tree):
-        """Register ``tree`` for a ``block_until_ready`` fence at close.
-
-        A no-op passthrough unless the tracer was enabled with sync
-        fencing (``REPRO_TRACE_SYNC=1``), so callers can wrap dispatch
-        results unconditionally."""
-        if self._tracer.sync_fence:
-            self._pending = (tree if self._pending is None
-                             else (self._pending, tree))
-        return tree
-
     def __enter__(self) -> "Span":
+        self._ann = _annotation(self.name, self.args)
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        if self._pending is not None:
-            import jax
-            jax.block_until_ready(self._pending)
-            self._pending = None
         t1 = time.perf_counter()
+        self._ann.__exit__(exc_type, exc, tb)
         if exc_type is not None:
             self.args.setdefault("error", exc_type.__name__)
         self._tracer._record(self.name, self._t0, t1, self.args)
@@ -113,9 +108,8 @@ class Span:
 class Tracer:
     """Collects span events; thread-safe; one per process is plenty."""
 
-    def __init__(self, *, sync_fence: bool = False):
+    def __init__(self):
         self.enabled = False
-        self.sync_fence = sync_fence
         self._events: list[dict] = []
         self._lock = threading.Lock()
         self._origin = time.perf_counter()
@@ -138,6 +132,13 @@ class Tracer:
             ev["args"] = {k: _jsonable(v) for k, v in args.items()}
         with self._lock:
             self._events.append(ev)
+
+    def complete(self, name: str, t0: float, t1: float, **args) -> None:
+        """Record a span that already ended, from ``time.perf_counter``
+        stamps ``t0`` and ``t1`` (a phase that crossed calls)."""
+        if not self.enabled:
+            return
+        self._record(name, t0, t1, args)
 
     def _record(self, name: str, t0: float, t1: float,
                 args: dict) -> None:
@@ -190,12 +191,8 @@ def is_enabled() -> bool:
     return _TRACER.enabled
 
 
-def enable(*, sync: bool | None = None) -> None:
-    """Turn the module tracer on.  ``sync`` overrides the
-    ``REPRO_TRACE_SYNC`` env gate for block-until-ready fences."""
-    if sync is None:
-        sync = os.environ.get(SYNC_ENV, "") == "1"
-    _TRACER.sync_fence = sync
+def enable() -> None:
+    """Turn the module tracer on."""
     _TRACER.enabled = True
 
 
@@ -212,6 +209,12 @@ def span(name: str, **args):
 
 def instant(name: str, **args) -> None:
     _TRACER.instant(name, **args)
+
+
+def complete(name: str, t0: float, t1: float, **args) -> None:
+    """A phase that already ended, on the module tracer (see
+    :meth:`Tracer.complete`)."""
+    _TRACER.complete(name, t0, t1, **args)
 
 
 def export(path) -> None:

@@ -46,6 +46,7 @@ from repro.serve.kv_cache import (PageAllocator, extract_token, gather_pages,
                                   init_pools, pages_needed, scatter_token)
 from repro.serve.registry import AdapterRegistry
 from repro.serve.scheduler import Scheduler
+from repro.utils import scope
 
 Array = jax.Array
 
@@ -88,8 +89,9 @@ def _decode_step_fn(cfg, sites: tuple):
             leaf = dict(node[keys[-1]])
             st = stacks[site]
             # (L, cap, m, r) -> (L, B, m, r): per-request adapters
-            leaf["lora_a"] = jnp.take(st["lora_a"], ad_slots, axis=1)
-            leaf["lora_b"] = jnp.take(st["lora_b"], ad_slots, axis=1)
+            with scope("lora_gather"):
+                leaf["lora_a"] = jnp.take(st["lora_a"], ad_slots, axis=1)
+                leaf["lora_b"] = jnp.take(st["lora_b"], ad_slots, axis=1)
             node[keys[-1]] = leaf
         K = gather_pages(k_pool, page_tables)
         V = gather_pages(v_pool, page_tables)
@@ -202,62 +204,105 @@ class ServeEngine:
         return rid
 
     def step(self) -> list[int]:
-        """One engine iteration; returns rids finished this step."""
+        """One engine iteration; returns rids finished this step.
+
+        Its spans tile the host's work: ``serve.schedule`` (admission),
+        then per rank bucket ``serve.prepare`` (the host arrays),
+        ``serve.decode`` (``serve.upload``, ``serve.dispatch``,
+        ``serve.sync``) and ``serve.commit`` (tokens, retirement), and a
+        last ``serve.commit`` for the KV gauges.  Each request's phases
+        are recorded as they end: ``serve.queue`` (submit to admit),
+        ``serve.prefill`` (admit to first token), ``serve.generate``
+        (first token to finish), each with its ``rid``."""
         with obs_trace.span("serve.step", step=self.steps) as step_sp:
-            active = self.scheduler.tick()
-            now = time.perf_counter()
-            queue_hist = obs_metrics.histogram(obs_names.SERVE_QUEUE_WAIT)
-            for entries in active.values():
-                for _slot, rid in entries:
-                    r = self._reqs[rid]
-                    if r.t_admit == 0.0:
-                        r.t_admit = now
-                        queue_hist.observe(now - r.t_submit)
+            with obs_trace.span("serve.schedule"):
+                active = self.scheduler.tick()
+                self._admit(active)
             finished: list[int] = []
             for rank in sorted(b for b, ent in active.items() if ent):
                 entries = active[rank]
-                stacks = self.registry.stacks(rank)
-                B = self.bucket_capacity
-                ad = np.zeros((B,), np.int32)
-                toks = np.zeros((B, 1), np.int32)
-                lens = np.zeros((B,), np.int32)
-                pt = np.zeros((B, self._maxp), np.int32)
-                for slot, rid in entries:
-                    r = self._reqs[rid]
-                    ad[slot] = r.ad_slot
-                    toks[slot, 0] = r.next_token()
-                    lens[slot] = r.pos
-                    pages = self.scheduler.pages_of(rid)
-                    pt[slot, :len(pages)] = pages
+                with obs_trace.span("serve.prepare", rank=rank):
+                    stacks = self.registry.stacks(rank)
+                    ad, toks, lens, pt = self._host_arrays(entries)
                 with obs_trace.span("serve.decode", rank=rank,
                                     batch=len(entries)):
-                    nxt, self._k_pool, self._v_pool = self._exec(
-                        self._base, stacks, jnp.asarray(ad), self._k_pool,
-                        self._v_pool, jnp.asarray(pt), jnp.asarray(lens),
-                        jnp.asarray(toks))
-                    nxt = np.asarray(nxt)    # host sync inside the span
-                for slot, rid in entries:
-                    r = self._reqs[rid]
-                    r.pos += 1
-                    if r.pos >= len(r.prompt):
-                        tok = int(nxt[slot])
-                        r.out.append(tok)
-                        obs_metrics.counter(obs_names.SERVE_TOKENS).inc()
-                        if len(r.out) == 1:
-                            r.t_first = time.perf_counter()
-                            obs_metrics.histogram(
-                                obs_names.SERVE_TTFT).observe(
-                                r.t_first - r.t_submit)
-                        if len(r.out) >= r.max_new or tok == r.eos:
-                            r.t_finish = time.perf_counter()
-                            self._retire_metrics(r)
-                            self.scheduler.retire(rid)
-                            finished.append(rid)
-            self._kv_metrics()
-            obs_metrics.counter(obs_names.SERVE_STEPS).inc()
+                    with obs_trace.span("serve.upload"):
+                        ad, pt = jnp.asarray(ad), jnp.asarray(pt)
+                        lens, toks = jnp.asarray(lens), jnp.asarray(toks)
+                    with obs_trace.span("serve.dispatch"):
+                        nxt, self._k_pool, self._v_pool = self._exec(
+                            self._base, stacks, ad, self._k_pool,
+                            self._v_pool, pt, lens, toks)
+                    with obs_trace.span("serve.sync"):
+                        nxt = np.asarray(nxt)    # host sync inside the span
+                with obs_trace.span("serve.commit", rank=rank):
+                    self._commit(entries, nxt, finished)
+            with obs_trace.span("serve.commit"):
+                self._kv_metrics()
+                obs_metrics.counter(obs_names.SERVE_STEPS).inc()
             self.steps += 1
             step_sp.set(finished=len(finished))
         return finished
+
+    def _admit(self, active: dict) -> None:
+        """Stamp the requests that got a slot this step."""
+        now = time.perf_counter()
+        queue_hist = obs_metrics.histogram(obs_names.SERVE_QUEUE_WAIT)
+        for entries in active.values():
+            for _slot, rid in entries:
+                r = self._reqs[rid]
+                if r.t_admit == 0.0:
+                    r.t_admit = now
+                    queue_hist.observe(now - r.t_submit)
+                    obs_trace.complete("serve.queue", r.t_submit, now,
+                                       rid=rid, tenant=r.tenant)
+
+    def _host_arrays(self, entries) -> tuple:
+        """A bucket's adapter slots, next tokens, lengths and page
+        tables, one row per slot."""
+        B = self.bucket_capacity
+        ad = np.zeros((B,), np.int32)
+        toks = np.zeros((B, 1), np.int32)
+        lens = np.zeros((B,), np.int32)
+        pt = np.zeros((B, self._maxp), np.int32)
+        for slot, rid in entries:
+            r = self._reqs[rid]
+            ad[slot] = r.ad_slot
+            toks[slot, 0] = r.next_token()
+            lens[slot] = r.pos
+            pages = self.scheduler.pages_of(rid)
+            pt[slot, :len(pages)] = pages
+        return ad, toks, lens, pt
+
+    def _commit(self, entries, nxt: np.ndarray, finished: list) -> None:
+        """Advance each row of a bucket by the token it was given, keep
+        the generated ones, and retire the requests that are done."""
+        prompt_rows = 0
+        for slot, rid in entries:
+            r = self._reqs[rid]
+            prompt_rows += r.pos < len(r.prompt)
+            r.pos += 1
+            if r.pos >= len(r.prompt):
+                tok = int(nxt[slot])
+                r.out.append(tok)
+                obs_metrics.counter(obs_names.SERVE_TOKENS).inc()
+                if len(r.out) == 1:
+                    r.t_first = time.perf_counter()
+                    obs_metrics.histogram(obs_names.SERVE_TTFT).observe(
+                        r.t_first - r.t_submit)
+                    obs_trace.complete("serve.prefill", r.t_admit,
+                                       r.t_first, rid=rid,
+                                       prompt=len(r.prompt))
+                if len(r.out) >= r.max_new or tok == r.eos:
+                    r.t_finish = time.perf_counter()
+                    obs_trace.complete("serve.generate", r.t_first,
+                                       r.t_finish, rid=rid,
+                                       tokens=len(r.out))
+                    self._retire_metrics(r)
+                    self.scheduler.retire(rid)
+                    finished.append(rid)
+        if prompt_rows:
+            obs_metrics.counter(obs_names.SERVE_PROMPT_ROWS).inc(prompt_rows)
 
     def _retire_metrics(self, r: _Request) -> None:
         obs_metrics.counter(obs_names.SERVE_FINISHED).inc()

@@ -35,6 +35,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.utils import scope
+
 Array = jax.Array
 
 SCRATCH_PAGE = 0
@@ -120,8 +122,9 @@ def gather_pages(pool: Array, page_tables: Array) -> Array:
     sequential replay."""
     L = pool.shape[0]
     B, maxp = page_tables.shape
-    g = pool[:, page_tables]                     # (L, B, maxp, P, Hkv, hd)
-    return g.reshape(L, B, maxp * pool.shape[2], *pool.shape[3:])
+    with scope("kv_cache"):
+        g = pool[:, page_tables]                 # (L, B, maxp, P, Hkv, hd)
+        return g.reshape(L, B, maxp * pool.shape[2], *pool.shape[3:])
 
 
 def extract_token(cache: Array, lengths: Array) -> Array:
@@ -129,9 +132,10 @@ def extract_token(cache: Array, lengths: Array) -> Array:
 
     cache (L, B, T, Hkv, hd); lengths (B,) -> (L, B, Hkv, hd)."""
     L, B = cache.shape[:2]
-    idx = jnp.broadcast_to(lengths[None, :, None, None, None],
-                           (L, B, 1, *cache.shape[3:]))
-    return jnp.take_along_axis(cache, idx, axis=2)[:, :, 0]
+    with scope("kv_cache"):
+        idx = jnp.broadcast_to(lengths[None, :, None, None, None],
+                               (L, B, 1, *cache.shape[3:]))
+        return jnp.take_along_axis(cache, idx, axis=2)[:, :, 0]
 
 
 def scatter_token(pool: Array, new: Array, page_tables: Array,
@@ -143,7 +147,9 @@ def scatter_token(pool: Array, new: Array, page_tables: Array,
     slots (all-zero page table, length 0) collide on the scratch page by
     construction — harmless, it is never mapped."""
     P = pool.shape[2]
-    logical = lengths // P                        # (B,) page slot in table
-    phys = jnp.take_along_axis(page_tables, logical[:, None], axis=1)[:, 0]
-    off = lengths % P
-    return pool.at[:, phys, off].set(new)
+    with scope("kv_cache"):
+        logical = lengths // P                    # (B,) page slot in table
+        phys = jnp.take_along_axis(page_tables, logical[:, None],
+                                   axis=1)[:, 0]
+        off = lengths % P
+        return pool.at[:, phys, off].set(new)

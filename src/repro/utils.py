@@ -12,7 +12,10 @@ import numpy as np
 
 # ---------------------------------------------------------------------------
 # Name scopes (flax-style paths, used to key calibration Grams and quantized
-# layer parameter subtrees).
+# layer parameter subtrees).  Each scope also enters ``jax.named_scope``, so
+# the same names reach every traced op's metadata (``op_name`` in the HLO,
+# ``tf_op`` in a profiler trace) with ``/`` in place of ``.``; the layer
+# names are declared in ``repro.obs.names.SCOPES``.
 # ---------------------------------------------------------------------------
 
 _state = threading.local()
@@ -26,9 +29,11 @@ def _scope_stack() -> list[str]:
 
 @contextlib.contextmanager
 def scope(name: str) -> Iterator[None]:
-    _scope_stack().append(str(name))
+    name = str(name)
+    _scope_stack().append(name)
     try:
-        yield
+        with jax.named_scope(name.replace(".", "/")):
+            yield
     finally:
         _scope_stack().pop()
 
@@ -69,12 +74,12 @@ class GramStore:
         if keep_leading:
             x3 = jnp.asarray(x, jnp.float32)
             x3 = x3.reshape(x3.shape[0], -1, x3.shape[-1])
-            with solver_precision():
+            with solver_precision(), scope("gram"):
                 h = jax.device_get(jnp.einsum("ecd,ecf->edf", x3, x3))
             cnt = x3.shape[1]
         else:
             x2 = jnp.asarray(x, jnp.float32).reshape(-1, x.shape[-1])
-            with solver_precision():
+            with solver_precision(), scope("gram"):
                 h = np.asarray(x2.T @ x2)
             cnt = x2.shape[0]
         if path in self.grams:

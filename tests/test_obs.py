@@ -70,20 +70,18 @@ def test_disabled_span_is_shared_singleton():
     assert s1 is s2
     with s1 as sp:
         assert sp.set(anything=True) is sp
-        tree = {"x": 1}
-        assert sp.sync(tree) is tree
     assert obs_trace.get_tracer().events() == []
 
 
 def test_disabled_tracer_results_bitwise_identical():
-    """Tracing (with sync fencing, the invasive mode) must not perturb
-    quantization numerics in any way."""
+    """Tracing (every span recorded and annotated for the profiler) must
+    not perturb quantization numerics in any way."""
     from repro.core.batched import quantize_layer_batch
     from repro.models.modules import QSpec
 
     qspec = QSpec(bits=4, group_size=16, rank=4)
     off = quantize_layer_batch(_tasks(), qspec, "cloq")
-    obs_trace.enable(sync=True)
+    obs_trace.enable()
     on = quantize_layer_batch(_tasks(), qspec, "cloq")
     obs_trace.disable()
     assert len(off) == len(on)
@@ -166,7 +164,7 @@ def test_traced_decorator():
 
     assert fn(3) == 6                        # disabled: plain call
     assert tr.events() == []
-    obs_trace.enable(sync=False)
+    obs_trace.enable()
     assert fn(4) == 8
     obs_trace.disable()
     (ev,) = tr.events()
@@ -174,19 +172,129 @@ def test_traced_decorator():
     assert calls == [3, 4]
 
 
-def test_sync_fence_registers_only_when_enabled():
-    tr = obs_trace.Tracer(sync_fence=True)
-    tr.enabled = True
-    x = jnp.arange(4.0)
-    with tr.span("fenced") as sp:
-        assert sp.sync(x) is x
-        assert sp._pending is not None
-    assert sp._pending is None               # consumed at close
-    tr2 = obs_trace.Tracer(sync_fence=False)
-    tr2.enabled = True
-    with tr2.span("unfenced") as sp2:
-        sp2.sync(x)
-        assert sp2._pending is None
+# --- spans on the profiler's clock -----------------------------------------
+
+
+def _profiled(tmp_path, enabled: bool):
+    """Open ``obs.probe`` around a device op inside a profiler trace on the
+    CPU; returns the span object and the trace's host events by name."""
+    from jax.profiler import ProfileData
+    if enabled:
+        obs_trace.enable()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with obs_trace.span("obs.probe", k=1) as sp:
+            jnp.arange(8.0).sum().block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    host = {}
+    for plane in ProfileData.from_file(str(path)).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    host.setdefault(ev.name, []).append(ev)
+    return sp, host
+
+
+@pytest.mark.parametrize("enabled", [True, False], ids=["on", "off"])
+def test_span_lands_in_a_profiler_trace_only_when_enabled(tmp_path, enabled):
+    sp, host = _profiled(tmp_path, enabled)
+    if enabled:
+        (ev,) = host["obs.probe"]
+        assert {k: v for k, v in ev.stats} == {"k": 1}
+        (rec,) = obs_trace.get_tracer().events()
+        # the same interval, to the tracer's and the profiler's clocks
+        assert ev.duration_ns == pytest.approx(rec["dur"] * 1e3, rel=0.5)
+    else:
+        assert sp is obs_trace.span("other")        # the shared no-op
+        assert "obs.probe" not in host
+        assert obs_trace.get_tracer().events() == []
+
+
+def test_complete_records_a_phase_that_already_ended():
+    obs_trace.complete("serve.prefill", 1.0, 2.5, rid=3)   # off: nothing
+    assert obs_trace.get_tracer().events() == []
+    obs_trace.enable()
+    obs_trace.complete("serve.prefill", 1.0, 2.5, rid=3)
+    (ev,) = obs_trace.get_tracer().events()
+    assert ev["ph"] == "X" and ev["args"] == {"rid": 3}
+    assert ev["dur"] == pytest.approx(1.5e6)
+
+
+def _op_name_scopes(compiled_text: str) -> set:
+    """Every name-stack component of the compiled HLO's ``op_name``s, with
+    the transforms JAX writes around a name (``jvp(attn)``) taken off."""
+    import re
+    out = set()
+    for op_name in re.findall(r'op_name="([^"]*)"', compiled_text):
+        for part in op_name.split("/"):
+            while re.fullmatch(r"\w+\((.*)\)", part):
+                part = re.fullmatch(r"\w+\((.*)\)", part).group(1)
+            out.add(part)
+    return out
+
+
+def _tiny_quantized():
+    from repro.core.pipeline import quantize_model
+    from repro.core.recipe import QuantRecipe
+    from repro.models.modules import QSpec
+    from repro.models.transformer import ModelConfig, init_params
+
+    cfg = ModelConfig(name="t", family="dense", n_layers=2, d_model=32,
+                      vocab=64, n_heads=4, n_kv_heads=2, d_ff=64,
+                      dtype=jnp.float32)
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    calib = [{"tokens": np.random.default_rng(0).integers(1, 64, (2, 16))}]
+    return quantize_model(params, cfg, calib, recipe=QuantRecipe.single(
+        "cloq", QSpec(bits=4, group_size=16, rank=4)))[:2]
+
+
+def _serve_step_text(qp, qcfg) -> str:
+    from repro.serve import AdapterRegistry, ServeEngine, adapters_from_tree
+    reg = AdapterRegistry.from_model(qp, capacity=2)
+    reg.register("t0", adapters_from_tree(qp))
+    eng = ServeEngine(qp, qcfg, reg, page_size=4, max_len=16,
+                      bucket_capacity=2)
+    B, rank = eng.bucket_capacity, reg.slot_of("t0")[0]
+    z = jnp.zeros((B,), jnp.int32)
+    return eng._exec.lower(
+        eng._base, reg.stacks(rank), z, eng._k_pool, eng._v_pool,
+        jnp.zeros((B, eng._maxp), jnp.int32), z,
+        jnp.zeros((B, 1), jnp.int32)).compile().as_text()
+
+
+def _train_step_text(qp, qcfg) -> str:
+    from repro.launch.steps import build_state, make_train_step
+    from repro.models.parallel import LOCAL
+    from repro.optim import OptConfig
+    ocfg = OptConfig(lr=1e-2, trainable="lora", total_steps=2)
+    batch = {"tokens": jnp.ones((2, 16), jnp.int32),
+             "labels": jnp.ones((2, 16), jnp.int32)}
+    step = jax.jit(make_train_step(qcfg, ocfg, LOCAL))
+    return step.lower(build_state(qp, ocfg), batch).compile().as_text()
+
+
+LINEAR_SCOPES = {"q", "k", "v", "o", "gate", "up", "down", "dequant", "lora"}
+STEP_SCOPES = {
+    "serve": {"embed", "attn", "mlp", "lm_head", "kv_cache", "lora_gather",
+              *LINEAR_SCOPES},
+    "train": {"embed", "attn", "mlp", "lm_head", *LINEAR_SCOPES},
+}
+
+
+@pytest.mark.parametrize("step", sorted(STEP_SCOPES))
+def test_layer_scopes_reach_the_compiled_op_names(step):
+    """The names ``repro.utils.scope`` enters are in the op metadata the
+    profiler reports as ``tf_op`` (the serve step on the XLA dequant
+    path here; the chip runs the kernels instead)."""
+    want = STEP_SCOPES[step]
+    assert want <= set(obs_names.SCOPES)
+    qp, qcfg = _tiny_quantized()
+    text = {"serve": _serve_step_text,
+            "train": _train_step_text}[step](qp, qcfg)
+    missing = want - _op_name_scopes(text)
+    assert not missing, f"scopes absent from the {step} step: {missing}"
 
 
 # --- metrics ----------------------------------------------------------------
@@ -260,7 +368,7 @@ def _quant_once():
 
 @pytest.mark.fault
 def test_fault_run_counters_deterministic_and_health_visible():
-    obs_trace.enable(sync=False)
+    obs_trace.enable()
     _quant_once()
     obs_trace.disable()
     first = obs_metrics.snapshot()
@@ -339,7 +447,7 @@ def test_log_sink_swap_and_level():
 def test_session_exports_trace_and_metrics(tmp_path):
     from repro import obs
     tpath, mpath = tmp_path / "t.json", tmp_path / "m.json"
-    with obs.session(tpath, mpath, sync=False):
+    with obs.session(tpath, mpath):
         assert obs_trace.is_enabled()
         with obs_trace.span("work", step=1):
             obs_metrics.counter(obs_names.TRAIN_STEPS).inc()
@@ -354,7 +462,7 @@ def test_session_exports_on_exception(tmp_path):
     from repro import obs
     tpath = tmp_path / "t.json"
     with pytest.raises(RuntimeError):
-        with obs.session(tpath, None, sync=False):
+        with obs.session(tpath, None):
             with obs_trace.span("doomed"):
                 pass
             raise RuntimeError("crash")

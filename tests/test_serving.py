@@ -207,3 +207,55 @@ def test_page_reuse_across_waves(model):
         _engine(qp, qcfg, reg, bucket_capacity=2, n_pages=7), reqs,
         sequential=True)
     assert batched == sequential
+
+
+STEP_CHILDREN = ("serve.schedule", "serve.prepare", "serve.decode",
+                 "serve.upload", "serve.dispatch", "serve.sync",
+                 "serve.commit")
+
+
+def test_engine_records_each_requests_phases_and_step_spans(model):
+    """With the tracer on, every request leaves one ``serve.queue``,
+    ``serve.prefill`` and ``serve.generate`` with its ``rid``, each the
+    interval between the engine's own stamps, and each engine step is
+    tiled by its host-boundary spans."""
+    from repro.obs import metrics as obs_metrics
+    from repro.obs import names as obs_names
+    from repro.obs import trace as obs_trace
+
+    qp, qcfg = model
+    reg, names = _registry(qp)
+    eng = _engine(qp, qcfg, reg, bucket_capacity=2)
+    obs_trace.get_tracer().clear()
+    obs_metrics.reset()
+    obs_trace.enable()
+    try:
+        prompts = [[1 + i, 2, 3][:1 + i % 3] for i in range(5)]
+        rids = [eng.submit(p, names[i % len(names)], 3)
+                for i, p in enumerate(prompts)]
+        eng.run()
+    finally:
+        obs_trace.disable()
+    events = obs_trace.get_tracer().events()
+    obs_trace.get_tracer().clear()
+    phases = {}
+    for e in events:
+        if e["name"] in ("serve.queue", "serve.prefill", "serve.generate"):
+            key = (e["name"], e["args"]["rid"])
+            assert key not in phases
+            phases[key] = e["dur"] / 1e6
+    for rid in rids:
+        r = eng._reqs[rid]
+        want = {"serve.queue": r.t_admit - r.t_submit,
+                "serve.prefill": r.t_first - r.t_admit,
+                "serve.generate": r.t_finish - r.t_first}
+        for name, secs in want.items():
+            assert phases[(name, rid)] == pytest.approx(secs, abs=1e-9)
+    names_seen = {e["name"] for e in events}
+    assert set(STEP_CHILDREN) <= names_seen
+    steps = [e for e in events if e["name"] == "serve.step"]
+    assert len(steps) == eng.steps
+    # prompt rows counted where they are fed: one per prompt token
+    assert obs_metrics.counter(obs_names.SERVE_PROMPT_ROWS).value == sum(
+        len(p) for p in prompts)
+    obs_metrics.reset()

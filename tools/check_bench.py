@@ -15,7 +15,7 @@ loses a speedup fails verification even when every test stays green:
 * ``serve_bench.json -> speedup >= 3.0`` — the continuous-batching serving
   engine must stay well ahead of the static-slot baseline;
 * ``table10_init_cost.json -> obs_overhead_row.overhead_pct <= 20.0`` — a
-  ceiling, not a floor: span tracing with sync fencing must stay cheap
+  ceiling, not a floor: span tracing must stay cheap
   enough to leave on for any diagnostic run;
 * ``metrics-*.json`` counter floors — the benchmark runs must actually
   exercise what they claim (warm compile-cache hits, finished serve
