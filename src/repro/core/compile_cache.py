@@ -119,21 +119,24 @@ class CompileCache:
         raise TypeError(
             f"cannot coerce {type(obj).__name__} to CompileCache")
 
-    def key(self, kind: str, parts: dict, args) -> str:
+    def key(self, kind: str, parts: dict, args,
+            donate_argnums: tuple = ()) -> str:
         return canonical_digest({
             "kind": kind, "parts": parts, "sig": _signature(args),
+            "donate": list(donate_argnums),
             "jax": self.jax_version, "backend": self.backend,
             "devices": jax.device_count()})
 
     def _path(self, key: str) -> str:
         return os.path.join(self.directory, f"{key}.bin")
 
-    def get(self, kind: str, parts: dict, fn: Callable,
-            args: tuple) -> tuple[Any, bool]:
+    def get(self, kind: str, parts: dict, fn: Callable, args: tuple,
+            donate_argnums: tuple = ()) -> tuple[Any, bool]:
         """Return ``(executable, hit)`` for ``fn`` specialized to
         ``args``'s shapes/dtypes.  The executable is called exactly like
-        ``jax.jit(fn)`` at those shapes."""
-        key = self.key(kind, parts, args)
+        ``jax.jit(fn, donate_argnums=donate_argnums)`` at those shapes;
+        the donation is part of the key and of the persisted executable."""
+        key = self.key(kind, parts, args, donate_argnums)
         if key in self._mem:
             self._tally("hits")
             return self._mem[key], True
@@ -162,7 +165,8 @@ class CompileCache:
                     os.remove(path)
                 except OSError:
                     pass
-        compiled = jax.jit(fn).lower(*_abstract(args)).compile()
+        compiled = jax.jit(fn, donate_argnums=donate_argnums).lower(
+            *_abstract(args)).compile()
         self._tally("misses")
         self._mem[key] = compiled
         if not self._portable(compiled):
@@ -218,12 +222,14 @@ class PersistedFunction:
     when the engine is given a cache)."""
 
     def __init__(self, cache: CompileCache, kind: str, parts: dict,
-                 fn: Callable):
+                 fn: Callable, donate_argnums: tuple = ()):
         self.cache = cache
         self.kind = kind
         self.parts = parts
         self.fn = fn
+        self.donate_argnums = tuple(donate_argnums)
 
     def __call__(self, *args):
-        compiled, _ = self.cache.get(self.kind, self.parts, self.fn, args)
+        compiled, _ = self.cache.get(self.kind, self.parts, self.fn, args,
+                                     self.donate_argnums)
         return compiled(*args)

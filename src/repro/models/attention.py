@@ -150,7 +150,8 @@ def attn_apply(p, cfg: AttnConfig, x: Array, *, qspec: QSpec | None = None,
 
 
 def attn_decode(p, cfg: AttnConfig, x: Array, cache: dict, *,
-                qspec: QSpec | None = None) -> tuple[Array, dict]:
+                qspec: QSpec | None = None
+                ) -> tuple[Array, dict, tuple[Array, Array]]:
     """Single-token decode. cache = {"k": (B,T,Hkv,hd), "v": ..., "idx": ()}.
 
     ``idx`` is normally a scalar (every row at the same position); the
@@ -163,7 +164,11 @@ def attn_decode(p, cfg: AttnConfig, x: Array, cache: dict, *,
     operand instead of the dense ``_sdpa`` mask — same math, the serving
     integration point for the paged KV cache.
 
-    With sliding_window, the cache is a ring buffer of size window."""
+    With sliding_window, the cache is a ring buffer of size window.
+
+    Returns ``(y, new_cache, (k_row, v_row))``: the rows ``(B, Hkv, hd)``
+    are what this step wrote into the cache, in its dtype, for a caller
+    that keeps the cache elsewhere (the serving engine's page pools)."""
     B, S, _ = x.shape
     assert S == 1, "decode processes one token"
     idx = cache["idx"]
@@ -172,10 +177,12 @@ def attn_decode(p, cfg: AttnConfig, x: Array, cache: dict, *,
     q, k, v = _project_qkv(p, cfg, x, positions, qspec)
     T = cache["k"].shape[1]
     slot = jnp.mod(idx, T) if cfg.sliding_window else idx
+    k_row = k[:, 0].astype(cache["k"].dtype)
+    v_row = v[:, 0].astype(cache["v"].dtype)
     if vec:
         rows = jnp.arange(B)
-        K = cache["k"].at[rows, slot].set(k[:, 0].astype(cache["k"].dtype))
-        V = cache["v"].at[rows, slot].set(v[:, 0].astype(cache["v"].dtype))
+        K = cache["k"].at[rows, slot].set(k_row)
+        V = cache["v"].at[rows, slot].set(v_row)
     else:
         K = jax.lax.dynamic_update_slice(
             cache["k"], k.astype(cache["k"].dtype), (0, slot, 0, 0))
@@ -200,7 +207,7 @@ def attn_decode(p, cfg: AttnConfig, x: Array, cache: dict, *,
         out = _sdpa(q, K, V, mask)
     with scope("o"):
         y = linear_apply(p["o"], out.reshape(B, 1, -1).astype(x.dtype), qspec)
-    return y, {"k": K, "v": V, "idx": idx + 1}
+    return y, {"k": K, "v": V, "idx": idx + 1}, (k_row, v_row)
 
 
 def cross_attn_apply(p, cfg: AttnConfig, x: Array, kv_src: Array, *,

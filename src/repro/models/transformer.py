@@ -544,9 +544,24 @@ def init_decode_cache(cfg: ModelConfig, batch: int, cache_len: int,
 def decode_step(params: dict, cfg: ModelConfig, cache: dict, tokens: Array, *,
                 pctx: PContext = LOCAL) -> tuple[Array, dict]:
     """One decode step. tokens (B, 1) int32. Returns (logits (B, V), cache)."""
+    logits, new_cache, _ = decode_step_rows(params, cfg, cache, tokens,
+                                            pctx=pctx)
+    return logits, new_cache
+
+
+def decode_step_rows(params: dict, cfg: ModelConfig, cache: dict,
+                     tokens: Array, *, pctx: PContext = LOCAL
+                     ) -> tuple[Array, dict, tuple[Array, Array] | None]:
+    """:func:`decode_step` that also returns, for the attention-cache
+    families (else None), each layer's new K/V row ``(L, B, Hkv, hd)`` in
+    the cache dtype: what the step wrote at ``cache["idx"]``.  A caller
+    that keeps the cache itself (the serving engine's page pools) reads
+    the rows and not the cache, and jit then drops the layer scan's full
+    ``(L, B, T, Hkv, hd)`` outputs."""
     x = embedding_apply(params["embed"], tokens).astype(cfg.dtype)
     q = cfg.quant
     idx = cache["idx"]
+    rows = None
 
     if cfg.family in ("dense", "moe", "encdec"):
         acfg = cfg.attn_cfg()
@@ -556,9 +571,9 @@ def decode_step(params: dict, cfg: ModelConfig, cache: dict, tokens: Array, *,
             bp, k_l, v_l, extras = inp
             with scope("attn"):
                 h = rmsnorm_apply(bp["ln1"], x)
-                y, new_kv = attn_decode(bp["attn"], acfg, h,
-                                        {"k": k_l, "v": v_l, "idx": idx},
-                                        qspec=q)
+                y, new_kv, (k_row, v_row) = attn_decode(
+                    bp["attn"], acfg, h, {"k": k_l, "v": v_l, "idx": idx},
+                    qspec=q)
                 x = x + y
             if cfg.family == "encdec":
                 cp = extras
@@ -575,24 +590,25 @@ def decode_step(params: dict, cfg: ModelConfig, cache: dict, tokens: Array, *,
                 with scope("mlp"):
                     y2 = swiglu_apply(bp["mlp"], rmsnorm_apply(bp["ln2"], x),
                                       q)
-            return x + y2, (new_kv["k"], new_kv["v"])
+            return x + y2, (new_kv["k"], new_kv["v"], k_row, v_row)
 
         blocks = params["blocks" if cfg.family != "encdec" else "dec_blocks"]
         extras = params.get("cross") if cfg.family == "encdec" else None
         if cfg.scan_layers:
             n = jax.tree.leaves(blocks)[0].shape[0]
             ex = extras if extras is not None else jnp.zeros((n,))
-            x, (K, V) = jax.lax.scan(
+            x, (K, V, Kr, Vr) = jax.lax.scan(
                 body, x, (blocks, cache["k"], cache["v"], ex))
-            new_cache = dict(cache, k=K, v=V, idx=idx + 1)
         else:
-            Ks, Vs = [], []
+            outs = []
             for i in sorted(blocks, key=int):
                 ex = extras[i] if extras is not None else None
-                x, (k_l, v_l) = body(x, (blocks[i], cache["k"][int(i)],
-                                         cache["v"][int(i)], ex))
-                Ks.append(k_l); Vs.append(v_l)
-            new_cache = dict(cache, k=jnp.stack(Ks), v=jnp.stack(Vs), idx=idx + 1)
+                x, out = body(x, (blocks[i], cache["k"][int(i)],
+                                  cache["v"][int(i)], ex))
+                outs.append(out)
+            K, V, Kr, Vr = (jnp.stack(a) for a in zip(*outs))
+        new_cache = dict(cache, k=K, v=V, idx=idx + 1)
+        rows = (Kr, Vr)
 
     elif cfg.family in ("ssm", "hybrid"):
         scfg = cfg.ssm_cfg()
@@ -631,9 +647,9 @@ def decode_step(params: dict, cfg: ModelConfig, cache: dict, tokens: Array, *,
                     blk = _with_site_lora(shared["block"], shared["site_lora"],
                                           site)
                     h2 = rmsnorm_apply(blk["ln1"], x)
-                    y2, nkv = attn_decode(blk["attn"], acfg, h2,
-                                          {"k": kv_k, "v": kv_v, "idx": idx},
-                                          qspec=q)
+                    y2, nkv, _ = attn_decode(
+                        blk["attn"], acfg, h2,
+                        {"k": kv_k, "v": kv_v, "idx": idx}, qspec=q)
                     x = x + y2
                     x = x + swiglu_apply(blk["mlp"],
                                          rmsnorm_apply(blk["ln2"], x), q)
@@ -675,7 +691,7 @@ def decode_step(params: dict, cfg: ModelConfig, cache: dict, tokens: Array, *,
                                               jnp.int32(site))
                         skv = cache["shared_kv"]
                         h2 = rmsnorm_apply(blk["ln1"], x)
-                        y2, nkv = attn_decode(
+                        y2, nkv, _ = attn_decode(
                             blk["attn"], acfg, h2,
                             {"k": skv["k"][site], "v": skv["v"][site],
                              "idx": idx}, qspec=q)
@@ -692,4 +708,4 @@ def decode_step(params: dict, cfg: ModelConfig, cache: dict, tokens: Array, *,
     x = rmsnorm_apply(params["final_norm"], x)
     head = params.get("head", params["embed"])
     logits = lm_head_apply(head, x)[:, 0, :]
-    return logits, new_cache
+    return logits, new_cache, rows

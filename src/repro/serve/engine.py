@@ -16,7 +16,8 @@ active rank bucket runs one fused decode — adapters for the bucket's
 requests are gathered from the stacked registry arrays *inside* jit
 (``jnp.take`` over the tenant-slot axis) and applied as one batched
 einsum per site, never a per-request loop.  KV pages are gathered to a
-contiguous per-request view, the new token's KV is scattered back, and
+contiguous per-request view, the layer scan hands back the new token's
+K/V rows, which are scattered into the donated pools in place, and
 per-request lengths drive positions/masks, so heterogeneous requests
 (different tenants, ranks, progress) share one device call.
 
@@ -41,9 +42,9 @@ from repro.models.parallel import LOCAL
 from repro.obs import metrics as obs_metrics
 from repro.obs import names as obs_names
 from repro.obs import trace as obs_trace
-from repro.models.transformer import decode_step
-from repro.serve.kv_cache import (PageAllocator, extract_token, gather_pages,
-                                  init_pools, pages_needed, scatter_token)
+from repro.models.transformer import decode_step_rows
+from repro.serve.kv_cache import (PageAllocator, gather_pages, init_pools,
+                                  pages_needed, scatter_token)
 from repro.serve.registry import AdapterRegistry
 from repro.serve.scheduler import Scheduler
 from repro.utils import scope
@@ -73,33 +74,44 @@ class _Request:
                 else self.out[-1])
 
 
+# the step's K and V pools: donated, so the token scatter writes in place
+_POOL_ARGS = (3, 4)
+
+
+def _with_adapters(base: dict, stacks: dict, ad_slots: Array,
+                   sites) -> dict:
+    """The base with each site's adapters gathered per request from the
+    registry's stacks: ``(L, cap, m, r) -> (L, B, m, r)``."""
+    params = dict(base)
+    params["blocks"] = dict(base["blocks"])
+    for site in sites:
+        keys = site.split(".")
+        node = _copy_to(params["blocks"], keys[:-1])
+        leaf = dict(node[keys[-1]])
+        st = stacks[site]
+        with scope("lora_gather"):
+            leaf["lora_a"] = jnp.take(st["lora_a"], ad_slots, axis=1)
+            leaf["lora_b"] = jnp.take(st["lora_b"], ad_slots, axis=1)
+        node[keys[-1]] = leaf
+    return params
+
+
 def _decode_step_fn(cfg, sites: tuple):
     """The raw (untraced) serving step for one (model config, site set) —
     jitted by :func:`_decode_exec` for the in-process path, or wrapped in a
     :class:`~repro.core.compile_cache.PersistedFunction` when the engine is
-    given a compile cache (cold-start skips the retrace)."""
+    given a compile cache (cold-start skips the retrace).  Both donate the
+    pools (``_POOL_ARGS``): the layer scan hands back each row's new K/V,
+    which is scattered straight into them."""
 
     def step_fn(base, stacks, ad_slots, k_pool, v_pool, page_tables,
                 lengths, tokens):
-        params = dict(base)
-        params["blocks"] = dict(base["blocks"])
-        for site in sites:
-            keys = site.split(".")
-            node = _copy_to(params["blocks"], keys[:-1])
-            leaf = dict(node[keys[-1]])
-            st = stacks[site]
-            # (L, cap, m, r) -> (L, B, m, r): per-request adapters
-            with scope("lora_gather"):
-                leaf["lora_a"] = jnp.take(st["lora_a"], ad_slots, axis=1)
-                leaf["lora_b"] = jnp.take(st["lora_b"], ad_slots, axis=1)
-            node[keys[-1]] = leaf
+        params = _with_adapters(base, stacks, ad_slots, sites)
         K = gather_pages(k_pool, page_tables)
         V = gather_pages(v_pool, page_tables)
         cache = {"k": K, "v": V, "idx": lengths}
-        logits, new_cache = decode_step(params, cfg, cache, tokens,
-                                        pctx=LOCAL)
-        newk = extract_token(new_cache["k"], lengths)
-        newv = extract_token(new_cache["v"], lengths)
+        logits, _, (newk, newv) = decode_step_rows(params, cfg, cache,
+                                                   tokens, pctx=LOCAL)
         k_pool = scatter_token(k_pool, newk, page_tables, lengths)
         v_pool = scatter_token(v_pool, newv, page_tables, lengths)
         nxt = jnp.argmax(logits[:, :cfg.vocab], axis=-1)
@@ -114,7 +126,7 @@ def _decode_exec(cfg, sites: tuple):
     module level so every engine instance (and every benchmark rep)
     shares the same executable; jit's own cache then specializes it per
     rank-bucket shape signature."""
-    return jax.jit(_decode_step_fn(cfg, sites))
+    return jax.jit(_decode_step_fn(cfg, sites), donate_argnums=_POOL_ARGS)
 
 
 def _copy_to(node: dict, keys: list[str]) -> dict:
@@ -178,7 +190,7 @@ class ServeEngine:
             self._exec = PersistedFunction(
                 self.compile_cache, "decode",
                 {"cfg": repr(self.cfg), "sites": list(sites)},
-                _decode_step_fn(self.cfg, sites))
+                _decode_step_fn(self.cfg, sites), donate_argnums=_POOL_ARGS)
         else:
             self._exec = _decode_exec(self.cfg, sites)
 

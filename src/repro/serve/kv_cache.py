@@ -127,17 +127,6 @@ def gather_pages(pool: Array, page_tables: Array) -> Array:
         return g.reshape(L, B, maxp * pool.shape[2], *pool.shape[3:])
 
 
-def extract_token(cache: Array, lengths: Array) -> Array:
-    """Pull the KV vector each request just wrote at position ``lengths``.
-
-    cache (L, B, T, Hkv, hd); lengths (B,) -> (L, B, Hkv, hd)."""
-    L, B = cache.shape[:2]
-    with scope("kv_cache"):
-        idx = jnp.broadcast_to(lengths[None, :, None, None, None],
-                               (L, B, 1, *cache.shape[3:]))
-        return jnp.take_along_axis(cache, idx, axis=2)[:, :, 0]
-
-
 def scatter_token(pool: Array, new: Array, page_tables: Array,
                   lengths: Array) -> Array:
     """Write one new KV vector per request into its physical page.

@@ -82,7 +82,7 @@ def test_window_ring_buffer_decode_matches_windowed_attention():
              "idx": jnp.zeros((), jnp.int32)}
     outs = []
     for t in range(S):
-        o, cache = attn_decode(p, acfg, x[:, t:t + 1], cache)
+        o, cache, _ = attn_decode(p, acfg, x[:, t:t + 1], cache)
         outs.append(o[:, 0])
     y_dec = jnp.stack(outs, axis=1)
     np.testing.assert_allclose(np.asarray(y_dec), np.asarray(y_full),

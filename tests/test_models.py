@@ -78,7 +78,7 @@ def test_attention_decode_matches_full():
              "idx": jnp.zeros((), jnp.int32)}
     outs = []
     for t in range(8):
-        o, cache = attn_decode(p, acfg, x[:, t:t + 1], cache)
+        o, cache, _ = attn_decode(p, acfg, x[:, t:t + 1], cache)
         outs.append(o[:, 0])
     np.testing.assert_allclose(np.asarray(jnp.stack(outs, 1)),
                                np.asarray(y_full), atol=1e-5)

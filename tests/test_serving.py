@@ -259,3 +259,128 @@ def test_engine_records_each_requests_phases_and_step_spans(model):
     assert obs_metrics.counter(obs_names.SERVE_PROMPT_ROWS).value == sum(
         len(p) for p in prompts)
     obs_metrics.reset()
+
+
+# --- KV write-back ----------------------------------------------------------
+
+def _full_cache_writeback(eng):
+    """The engine step with the plain write-back, as the reference: the
+    layer scan re-emits each row's whole cache, the token just written is
+    read back out of it at the row's length, then scattered."""
+    from repro.models.parallel import LOCAL
+    from repro.models.transformer import decode_step
+    from repro.serve.engine import _with_adapters
+    from repro.serve.kv_cache import gather_pages, scatter_token
+    cfg, sites = eng.cfg, tuple(eng.registry.sites())
+
+    def step(base, stacks, ad, k_pool, v_pool, pt, lens, toks):
+        params = _with_adapters(base, stacks, ad, sites)
+        cache = {"k": gather_pages(k_pool, pt),
+                 "v": gather_pages(v_pool, pt), "idx": lens}
+        logits, new = decode_step(params, cfg, cache, toks, pctx=LOCAL)
+        L, B = new["k"].shape[:2]
+        at = jnp.broadcast_to(lens[None, :, None, None, None],
+                              (L, B, 1, *new["k"].shape[3:]))
+        k_row, v_row = (jnp.take_along_axis(new[n], at, axis=2)[:, :, 0]
+                        for n in ("k", "v"))
+        nxt = jnp.argmax(logits[:, :cfg.vocab], axis=-1).astype(jnp.int32)
+        return (nxt, scatter_token(k_pool, k_row, pt, lens),
+                scatter_token(v_pool, v_row, pt, lens))
+    return jax.jit(step)
+
+
+def test_pool_writeback_matches_full_cache_reference(model):
+    """Every engine step leaves the pools bit-identical to the full-cache
+    write-back, and emits the same tokens: rows at mixed lengths, a write
+    that opens a row's second page, and an empty slot that writes to the
+    scratch page."""
+    qp, qcfg = model
+    reg, names = _registry(qp, ranks=(4,), per_rank=2)
+    eng = _engine(qp, qcfg, reg, bucket_capacity=3)
+    ref, exec_ = _full_cache_writeback(eng), eng._exec
+    seen = []
+
+    def checked(*args):
+        want = [np.asarray(a) for a in ref(*args)]   # before the donation
+        got = exec_(*args)
+        for w, g in zip(want, got):
+            np.testing.assert_array_equal(np.asarray(g), w)
+        seen.append((np.asarray(args[5]), np.asarray(args[6])))
+        return got
+
+    eng._exec = checked
+    eng.submit([1, 2, 3], names[0], max_new=4)
+    eng.step()
+    eng.step()
+    eng.submit([4], names[1], max_new=3)
+    eng.run()
+    P = eng._page
+    active = [(pt.any(axis=1), lens) for pt, lens in seen]
+    assert any(((lens[a] % P == 0) & (lens[a] > 0)).any()
+               for a, lens in active)                  # a page boundary
+    assert any(len(set(lens[a])) > 1 for a, lens in active)
+    assert all((~a).any() for a, _ in active)          # the scratch write
+
+
+@pytest.mark.parametrize("path", ["jit", "compile_cache_miss",
+                                  "compile_cache_hit"])
+def test_engine_step_donates_its_pools(model, tmp_path, path):
+    """The step writes the new rows into the pools in place: the arrays
+    it was given are deleted after it, on the jitted path and on the
+    persisted one, compiled or read back from disk."""
+    qp, qcfg = model
+    reg, names = _registry(qp, ranks=(4,), per_rank=1)
+    cache = None if path == "jit" else str(tmp_path)
+    if path == "compile_cache_hit":
+        warm = _engine(qp, qcfg, reg, compile_cache=cache)
+        warm.submit([1], names[0], max_new=1)
+        warm.run()
+    eng = _engine(qp, qcfg, reg, compile_cache=cache)
+    k_pool, v_pool = eng._k_pool, eng._v_pool
+    eng.submit([1, 2], names[0], max_new=2)
+    eng.step()
+    assert k_pool.is_deleted() and v_pool.is_deleted()
+    if path == "compile_cache_hit":
+        assert eng.compile_cache.hits >= 1 and eng.compile_cache.misses == 0
+    eng.run()
+
+
+def _hlo_shapes(text: str) -> dict:
+    """Every instruction of an HLO module's text: name -> (dims, line)."""
+    import re
+    out = {}
+    for m in re.finditer(r"^\s*(?:ROOT )?%(\S+) = \(?[a-z0-9]+\[([\d,]*)\]"
+                         r".*$", text, re.M):
+        dims = tuple(int(d) for d in m.group(2).split(",") if d)
+        out[m.group(1)] = (dims, m.group(0))
+    return out
+
+
+def test_kv_cache_ops_read_no_gathered_cache(model):
+    """No op under the ``kv_cache`` scope reads a whole ``(L, B, T, Hkv,
+    hd)`` cache: only ``gather_pages`` makes one, and the new rows come
+    out of the layer scan, not back out of the cache."""
+    import re
+    qp, qcfg = model
+    reg, names = _registry(qp, ranks=(4,), per_rank=1)
+    eng = _engine(qp, qcfg, reg, bucket_capacity=3)
+    B = eng.bucket_capacity
+    z = jnp.zeros((B,), jnp.int32)
+    text = eng._exec.lower(
+        eng._base, reg.stacks(reg.ranks()[0]), z, eng._k_pool, eng._v_pool,
+        jnp.zeros((B, eng._maxp), jnp.int32), z,
+        jnp.zeros((B, 1), jnp.int32)).compile().as_text()
+    hd = qcfg.head_dim or qcfg.d_model // qcfg.n_heads
+    full = (qcfg.n_layers, B, eng.max_len, qcfg.n_kv_heads, hd)
+    assert full != eng._k_pool.shape
+    shapes = _hlo_shapes(text)
+    readers = []
+    for name, (_, line) in shapes.items():
+        if "/kv_cache/" not in line:
+            continue
+        args = re.search(r" [\w-]+\(([^)]*)\)", line.split(", metadata")[0])
+        for arg in re.findall(r"%([\w.-]+)", args.group(1) if args else ""):
+            if shapes.get(arg, ((),))[0] == full:
+                readers.append(name)
+    assert any(dims == full for dims, _ in shapes.values())  # gather_pages
+    assert not readers, f"kv_cache ops read a full cache: {readers}"
