@@ -5,7 +5,8 @@ that the correctness check can be seen to fail: ``bench/control.py
 --faults`` reads them on the chip at a cell's own size, and the tests
 under ``bench/tests`` at a smoke size.  A serving fault takes the set-up
 job and wraps its engine's decode executable; a training fault maps the
-feed the step is given.
+feed the step is given.  Each job module names its own under ``FAULTS``,
+and a job plants the one given as its ``fault``.
 """
 from __future__ import annotations
 
@@ -60,8 +61,3 @@ def train_half_batch(feed):
         keep = jnp.arange(S) < S // 2
         return dict(b, labels=jnp.where(keep[None, :], b["labels"], -1))
     return broken
-
-
-SERVE = {"wrong_slot": serve_wrong_slot, "zero_adapters": serve_zero_adapters,
-         "altered_token": serve_altered_token}
-TRAIN = {"half_batch": train_half_batch}

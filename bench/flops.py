@@ -2,29 +2,63 @@
 
 These count what the algorithm requires, not what the program happens to
 execute: a later change to the program leaves them as they are.  ``c`` is
-a configuration file (``bench/configs/*.json``) of a dense decoder.
+a configuration file (``bench/configs/*.json``) of a decoder: dense, or,
+where it states ``n_experts``, one whose MLP is ``top_k`` routed experts
+of width ``d_ff_expert`` behind a router of ``d_model x n_experts``.
 """
 from __future__ import annotations
 
 
-def site_shapes(c: dict) -> dict:
-    """``{site: (in, out)}`` of one layer's linears."""
+def attention_sites(c: dict) -> dict:
+    """``{site: (in, out)}`` of one layer's attention projections."""
     d, hd = c["d_model"], c["head_dim"]
     q, kv = c["n_heads"] * hd, c["n_kv_heads"] * hd
     return {"attn.q": (d, q), "attn.k": (d, kv), "attn.v": (d, kv),
-            "attn.o": (q, d), "mlp.gate": (d, c["d_ff"]),
-            "mlp.up": (d, c["d_ff"]), "mlp.down": (c["d_ff"], d)}
+            "attn.o": (q, d)}
+
+
+def mlp_sites(c: dict) -> dict:
+    """``{site: (in, out)}`` of the MLP a token passes: the dense MLP's
+    linears, or one routed expert's."""
+    d = c["d_model"]
+    if c.get("n_experts"):
+        f = c["d_ff_expert"]
+        return {"moe.gate": (d, f), "moe.up": (d, f), "moe.down": (f, d)}
+    f = c["d_ff"]
+    return {"mlp.gate": (d, f), "mlp.up": (d, f), "mlp.down": (f, d)}
+
+
+def site_shapes(c: dict) -> dict:
+    """``{site: (in, out)}`` of one layer's quantized linears (an expert
+    site once, for one expert)."""
+    return {**attention_sites(c), **mlp_sites(c)}
+
+
+def routed(c: dict) -> int:
+    """How many times a token passes the MLP's sites: ``top_k`` routed
+    experts, or once."""
+    return c["top_k"] if c.get("n_experts") else 1
+
+
+def router_macs(c: dict) -> int:
+    """Multiply-adds of one layer's router, per token."""
+    return c["d_model"] * c["n_experts"] if c.get("n_experts") else 0
 
 
 def linear_macs(c: dict) -> int:
-    """Multiply-adds of every layer's base linears, per token."""
-    return c["n_layers"] * sum(m * n for m, n in site_shapes(c).values())
+    """Multiply-adds of every layer's base linears and router, per token."""
+    return c["n_layers"] * (
+        sum(m * n for m, n in attention_sites(c).values())
+        + routed(c) * sum(m * n for m, n in mlp_sites(c).values())
+        + router_macs(c))
 
 
 def lora_macs(c: dict, rank: int) -> int:
-    """Multiply-adds of ``x A B^T`` at every site, per token."""
-    return c["n_layers"] * sum(rank * (m + n)
-                               for m, n in site_shapes(c).values())
+    """Multiply-adds of ``x A B^T`` at every site a token passes (each
+    routed expert's own), per token."""
+    return c["n_layers"] * (
+        sum(rank * (m + n) for m, n in attention_sites(c).values())
+        + routed(c) * sum(rank * (m + n) for m, n in mlp_sites(c).values()))
 
 
 def attention_macs(c: dict, context: float) -> float:

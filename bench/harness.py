@@ -7,7 +7,10 @@ that is found here by name:
 * ``bench/configs/<config>.json``  the model configuration as it is run;
 * ``bench/traffic/<traffic>.json`` the mix, whose ``job`` names the window
   driver ``bench/jobs/<job>.py``;
-* ``bench/layers/<metric>.py``     one reader per per-layer metric.
+* ``bench/reference/<reference>.py`` the plain reference that the
+  configuration's ``reference`` key names (``dense`` where it names none);
+* ``bench/layers/<metric>.py``     one reader per per-layer metric;
+* ``bench/tests/tiny/<workload>.json`` the cell's smoke size for the tests.
 
 A run sets up (weights from the seed, warm-up of every shape the cell
 uses), measures for ``--seconds``, reads its metrics, frees the program's
@@ -60,8 +63,15 @@ def traffic_file(name: str) -> dict:
     return load_json(os.path.join(BENCH, "traffic", f"{name}.json"))
 
 
+def job_module(job: str):
+    """``bench/jobs/<job>.py``: its ``Job`` drives the window, its
+    ``LIMITS`` hold the correctness limits and its ``FAULTS`` the faults
+    planted under the timed path."""
+    return importlib.import_module(f"bench.jobs.{job}")
+
+
 def job_class(job: str):
-    return importlib.import_module(f"bench.jobs.{job}").Job
+    return job_module(job).Job
 
 
 def layer_reader(metric: str):

@@ -9,6 +9,10 @@ on the host clock after each engine step.  After the window closes,
 arrivals go on, so the load stays, until every request due in the window
 has its first token (at most ``DRAIN_S`` more seconds: one that has none
 by then has failed).
+
+The check runs the reference module that the configuration names
+(``bench.jobs.common.reference``).  A fault (``FAULTS``) takes the set-up
+job and breaks its engine underneath.
 """
 from __future__ import annotations
 
@@ -16,6 +20,7 @@ import time
 
 import numpy as np
 
+from bench import faults
 from bench import traffic as gen
 from bench.jobs import common
 
@@ -24,9 +29,14 @@ DRAIN_S = 60.0
 TRACE_AT, TRACE_S = 0.3, 3.0
 # requests the reference replays after the window, the longest among them
 CHECK_REQUESTS = 8
-# widest gap, in logits, by which a served token may lie below the
-# reference's best (PERF.md gives the readings it was set from)
-GAP_LIMIT = 0.25
+# served_logit_gap: the widest gap, in logits, by which a served token
+# may lie below the reference's best (PERF.md gives the readings it was
+# set from)
+LIMITS = {"served_logit_gap": 0.25}
+# faults planted under the engine at the end of set-up (``Job.fault``)
+FAULTS = {"wrong_slot": faults.serve_wrong_slot,
+          "zero_adapters": faults.serve_zero_adapters,
+          "altered_token": faults.serve_altered_token}
 
 
 def _percentile(xs, q: float) -> float:
@@ -35,6 +45,10 @@ def _percentile(xs, q: float) -> float:
 
 
 class Job:
+    # a fault planted under the timed path by the controls and the tests
+    # (one of ``FAULTS``): ``job -> None``, applied once set-up is done
+    fault = None
+
     def __init__(self, cell):
         self.cell = cell
         self.mix = cell.traffic
@@ -86,6 +100,8 @@ class Job:
         if cell.trace:
             obs.trace.get_tracer().clear()
             obs.trace.enable()
+        if self.fault:
+            self.fault(self)
 
     # -- window ------------------------------------------------------------
 
@@ -230,7 +246,8 @@ class Job:
                    "value": 0 if seqs else 1, "limit": 0}]
         if not seqs:
             return checks
-        gaps = served_gaps(self.cell.config, self.cell.seed, self.ranks,
+        gaps = served_gaps(common.reference(self.cell.config),
+                           self.cell.config, self.cell.seed, self.ranks,
                            self.share, seqs, self.max_len, control=control)
         self.gap_readings = {k: float(v.max()) for k, v in gaps.items()}
         print(f"serve check: requests={len(seqs)} "
@@ -239,5 +256,40 @@ class Job:
         checks.append({"name": "served_logit_gap",
                        "value": self.gap_readings[
                            "control" if control else "program"],
-                       "limit": GAP_LIMIT})
+                       "limit": LIMITS["served_logit_gap"]})
         return checks
+
+    @classmethod
+    def control(cls, cell, fault_names: list) -> list[dict]:
+        """For ``bench/control.py``: the cell served for its window at its
+        own load, its served tokens read against the reference in float32
+        (the program's reading) and in float8 (the control: the gap of the
+        token float8 puts first); then served again with each of
+        ``fault_names`` planted.  One reading each, with its verdict under
+        ``LIMITS``."""
+        from bench import harness
+        out = []
+        for fault in [None, *fault_names]:
+            job = cls(cell)
+            job.fault = FAULTS[fault] if fault else None
+            job.setup()
+            job.window(harness.Tracer(False))
+            job.release()
+            if fault:
+                checks = job.check()
+                out.append({"fault": fault, **_values(checks),
+                            "correct": harness.passed(checks)})
+                continue
+            ctrl = job.check(control=True)
+            prog = [dict(c, value=job.gap_readings["program"])
+                    if c["name"] == "served_logit_gap" else c for c in ctrl]
+            out.append({"program": _values(prog),
+                        "program_correct": harness.passed(prog),
+                        "control": _values(ctrl),
+                        "control_correct": harness.passed(ctrl),
+                        "finished": len(job.finished)})
+        return out
+
+
+def _values(checks: list) -> dict:
+    return {c["name"]: c["value"] for c in checks}

@@ -10,6 +10,10 @@ runs further steps of the same object, with ``AHEAD`` steps dispatched
 beyond the one it waits for, until ``--seconds`` have passed; then it
 sends nothing more and waits for every step sent.  Tokens per second are
 the tokens of every step sent over the time to the end of the last one.
+
+The check runs the reference module that the configuration names
+(``bench.jobs.common.reference``).  A fault (``FAULTS``) maps the feed
+that the checked steps are given.
 """
 from __future__ import annotations
 
@@ -18,6 +22,7 @@ import time
 
 import numpy as np
 
+from bench import faults
 from bench import traffic as gen
 from bench.jobs import common
 
@@ -32,6 +37,8 @@ TRACE_STEPS = 2
 # not compared: neither the float8 control nor a fault reads it far enough
 # above sound runs to set a limit between them
 LIMITS = {"grad_norm_rel": 4e-3, "update_norm_rel": 1.5e-3}
+# faults planted in the feed of the checked steps (``Job.fault``)
+FAULTS = {"half_batch": faults.train_half_batch}
 
 
 def _host(tree):
@@ -49,8 +56,9 @@ def lora_leaves(tree) -> dict:
 
 
 class Job:
-    # a fault planted under the timed path by the controls and the tests:
-    # ``feed -> feed`` applied to the batches the step is given
+    # a fault planted under the timed path by the controls and the tests
+    # (one of ``FAULTS``): ``feed -> feed`` applied to the batches the
+    # checked steps are given
     fault = None
 
     def __init__(self, cell):
@@ -160,15 +168,42 @@ class Job:
         """Losses, the first gradient and the change of the adapters over
         the checked steps, against the reference.  With ``control`` the
         reference in float8 takes the program's place."""
-        from bench.reference import dense
+        ref = common.reference(self.cell.config)
         if self.reference is None:
-            self.reference = reference_run(self, dense.EXACT)
-        got = reference_run(self, dense.FP8) if control else program_run(self)
+            self.reference = reference_run(self, ref.EXACT)
+        got = reference_run(self, ref.FP8) if control else program_run(self)
         self.readings = compare(got, self.reference)
         print(f"train check: {self.readings} "
               f"reference_losses={self.reference['losses']}", flush=True)
         return [{"name": k, "value": self.readings[k], "limit": LIMITS[k]}
                 for k in LIMITS]
+
+    @classmethod
+    def control(cls, cell, fault_names: list) -> list[dict]:
+        """For ``bench/control.py``: the readings of the program's checked
+        steps, of the reference in float8 in the program's place, and of
+        the program with each of ``fault_names`` planted, each with its
+        verdict under ``LIMITS``.  A step that returns its state unchanged
+        reads 1 on the change of the adapters by construction and needs no
+        run."""
+        from bench import harness
+        job = cls(cell)
+        job.setup()
+        job.release()
+        prog = job.check()
+        out = {"program": job.readings,
+               "program_correct": harness.passed(prog)}
+        ctrl = job.check(control=True)
+        out.update(control=job.readings, control_correct=harness.passed(ctrl))
+        for name in fault_names:
+            broken = cls(cell)
+            broken.fault = FAULTS[name]
+            broken.reference = job.reference
+            broken.setup()
+            broken.release()
+            checks = broken.check()
+            out[name] = dict(broken.readings, correct=harness.passed(checks))
+        return [out]
 
 
 def leaf_gaps(got: dict, want: dict, leaves) -> dict:
@@ -191,12 +226,14 @@ def reference_run(job, prec) -> dict:
     after the last step (flat ``{"attn.q.lora_a": array}``)."""
     import jax
     from bench import weights
-    from bench.reference import dense, train_check
+    from bench.reference import train_check
 
-    dims = dense.Dims.of(job.cell.config)
+    ref = common.reference(job.cell.config)
+    dims = ref.Dims.of(job.cell.config)
     key = weights.seed_key(job.cell.seed)
     opt = job.opt()
-    p0 = train_check.initial_lora(key, dims, int(job.cell.config["lora_rank"]),
+    p0 = train_check.initial_lora(key, ref.sites(dims), dims.n_layers,
+                                  int(job.cell.config["lora_rank"]),
                                   job.cell.config["lora_dtype"])
     flat = lambda t: {f"{s}.{k}": np.asarray(v[k], np.float32)  # noqa: E731
                       for s, v in t.items() for k in v}
@@ -204,8 +241,8 @@ def reference_run(job, prec) -> dict:
     state = jax.tree.map(lambda a: a * 0.0, {"mu": p0, "nu": p0})
     losses, first = [], None
     for i in range(CHECKED_STEPS):
-        loss, grad = train_check.loss_and_grad(params, key, job.feed(i), dims,
-                                               prec)
+        loss, grad = train_check.loss_and_grad(params, key, job.feed(i), ref,
+                                               dims, prec)
         losses.append(loss)
         params, state, clipped = train_check.adamw(params, grad, state,
                                                    i + 1, opt)

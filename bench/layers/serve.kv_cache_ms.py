@@ -1,5 +1,6 @@
-"""Paged KV cache traffic (``serve/kv_cache``: gather of each row's pages,
-extraction of the token just written, scatter back to the pool): device
+"""Paged KV cache traffic (``serve/kv_cache``: the gather of each row's
+pages into a contiguous view, and the scatter of each row's new keys and
+values, handed back by the layer scan, into the donated pools): device
 time of the ops under the ``kv_cache`` scope, per traced engine step, in
 ms.  Moves ``itl_p95_ms``.  Nothing to read where no op carries the
 scope."""

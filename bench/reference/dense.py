@@ -12,6 +12,9 @@ dequantized base.
 A ``Precision`` object does every matrix product: :data:`EXACT` runs them
 in float32 at ``highest``; :data:`FP8` casts both operands to float8_e4m3
 with one scale per tensor, the lower precision that the control runs.
+
+This is the reference of a configuration file that names none; it
+provides what ``bench.jobs.common.reference`` lists.
 """
 from __future__ import annotations
 
@@ -85,6 +88,12 @@ class Dims:
                 "mlp.up": (d, self.d_ff), "mlp.down": (self.d_ff, d)}[site]
 
 
+def sites(dims: Dims) -> dict:
+    """``{site: (lead, m, n)}`` of one layer's LoRA sites; no site of a
+    dense decoder has a leading axis."""
+    return {site: ((), *dims.site_shape(site)) for site in SITES}
+
+
 def base_layer(key, dims: Dims, layer) -> dict:
     """Layer ``layer``'s dequantized base ``{site: W (m, n) f32}``."""
     out = {}
@@ -117,6 +126,11 @@ def head(key, dims: Dims) -> jax.Array:
 
 def rmsnorm(x):
     return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + EPS)
+
+
+def final_norm(x):
+    """The RMSNorm before the head (its scale is ones)."""
+    return rmsnorm(x)
 
 
 def rope(x, theta: float):

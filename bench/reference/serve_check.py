@@ -1,7 +1,9 @@
 """Served tokens against the plain reference.
 
-For each sampled request the reference runs once over its prompt and its
-served tokens (teacher-forced) and reads, at each position that produced
+For each sampled request the reference module ``ref`` that the
+configuration names (``bench.jobs.common.reference`` lists what it
+provides) runs once over its prompt and its served tokens
+(teacher-forced) and reads, at each position that produced
 a served token, how far that token's logit lies below the reference's
 best.  With ``control=True`` it also runs the same forward in float8 and
 reads the gap of the token that float8 puts first: the control, which has
@@ -16,7 +18,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from bench import weights
-from bench.reference import dense
 
 HIGHEST = jax.lax.Precision.HIGHEST
 
@@ -27,13 +28,16 @@ def tenant_key(seed: int, tenant: int) -> jax.Array:
         jax.random.fold_in(weights.seed_key(seed), 0x7465), tenant)
 
 
-def _adapters(dims, keys, ranks, layer, share):
+def _adapters(ref, dims, keys, ranks, layer, share):
     """Each tenant's layer-``layer`` adapters, padded to the largest rank
     with zeros: ``{site: (A (U, m, R), B (U, n, R))}`` in f32."""
     rmax = max(ranks)
     out = {}
-    for site in dense.SITES:
-        m, n = dims.site_shape(site)
+    for site, (lead, m, n) in ref.sites(dims).items():
+        if lead:
+            raise NotImplementedError(
+                f"site {site!r} has a leading axis {lead}: the serve job "
+                "draws tenant adapters per layer only")
         As, Bs = [], []
         for k, r in zip(keys, ranks):
             ad = weights.adapter_layer(
@@ -47,29 +51,29 @@ def _adapters(dims, keys, ranks, layer, share):
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("dims", "ranks", "share", "precs"))
-def _layer(xs, layer, key, tkeys, rows, *, dims, ranks, share, precs):
-    base = dense.base_layer(key, dims, layer)
-    ad = _adapters(dims, list(tkeys), ranks, layer, share)
+                   static_argnames=("ref", "dims", "ranks", "share", "precs"))
+def _layer(xs, layer, key, tkeys, rows, *, ref, dims, ranks, share, precs):
+    base = ref.base_layer(key, dims, layer)
+    ad = _adapters(ref, dims, list(tkeys), ranks, layer, share)
     lora = {s: (a[rows], b[rows]) for s, (a, b) in ad.items()}
-    return tuple(dense.block(p, dims, x, base, lora)
+    return tuple(ref.block(p, dims, x, base, lora)
                  for p, x in zip(precs, xs))
 
 
-@functools.partial(jax.jit, static_argnames=("dims", "control"))
-def _gaps(xs, targets, key, *, dims, control):
-    head = dense.head(key, dims)
+@functools.partial(jax.jit, static_argnames=("ref", "dims", "control"))
+def _gaps(xs, targets, key, *, ref, dims, control):
+    head = ref.head(key, dims)
 
     def row(args):
         hs, tgt = args
-        logits = jnp.einsum("td,dv->tv", dense.rmsnorm(hs[0]), head,
+        logits = jnp.einsum("td,dv->tv", ref.final_norm(hs[0]), head,
                             precision=HIGHEST)
         best = jnp.max(logits, axis=-1)
         served = jnp.take_along_axis(logits, jnp.maximum(tgt, 0)[:, None],
                                      axis=-1)[:, 0]
         out = [jnp.where(tgt >= 0, best - served, 0.0)]
         if control:
-            low = dense.FP8.einsum("td,dv->tv", dense.rmsnorm(hs[1]), head)
+            low = ref.FP8.einsum("td,dv->tv", ref.final_norm(hs[1]), head)
             first = jnp.argmax(low, axis=-1)
             ctrl = jnp.take_along_axis(logits, first[:, None], axis=-1)[:, 0]
             out.append(jnp.where(tgt >= 0, best - ctrl, 0.0))
@@ -78,15 +82,16 @@ def _gaps(xs, targets, key, *, dims, control):
     return jax.lax.map(row, (xs, targets))
 
 
-def served_gaps(config: dict, seed: int, ranks: list[int], share: float,
-                seqs: list, length: int, control: bool = False) -> dict:
+def served_gaps(ref, config: dict, seed: int, ranks: list[int],
+                share: float, seqs: list, length: int,
+                control: bool = False) -> dict:
     """``seqs`` is ``[(tenant, prompt, served tokens), ...]``; every
     sequence is padded to ``length`` positions; tenants' adapters are
     drawn as :func:`bench.weights.adapter_layer` draws them with
     ``share``.  Returns the gap of every
     served token, ``{"program": array}`` and with ``control`` also
     ``{"control": array}`` (the float8 forward's first choice)."""
-    dims = dense.Dims.of(config)
+    dims = ref.Dims.of(config)
     key = weights.seed_key(seed)
     N = len(seqs)
     tokens = np.zeros((N, length), np.int32)
@@ -98,17 +103,17 @@ def served_gaps(config: dict, seed: int, ranks: list[int], share: float,
         targets[i, len(prompt) - 1:len(prompt) - 1 + len(out)] = out
         rows[i] = tenant
     tkeys = jnp.stack([tenant_key(seed, t) for t in range(len(ranks))])
-    emb = dense.embedding(key, dims)
+    emb = ref.embedding(key, dims)
     x = emb[jnp.asarray(tokens)]
     del emb
-    precs = (dense.EXACT, dense.FP8) if control else (dense.EXACT,)
+    precs = (ref.EXACT, ref.FP8) if control else (ref.EXACT,)
     xs = tuple(x for _ in precs)
     for layer in range(dims.n_layers):
         xs = _layer(xs, jnp.int32(layer), key, tkeys, jnp.asarray(rows),
-                    dims=dims, ranks=tuple(ranks), share=share,
+                    ref=ref, dims=dims, ranks=tuple(ranks), share=share,
                     precs=precs)
-    got = _gaps(jnp.stack(xs, axis=1), jnp.asarray(targets), key, dims=dims,
-                control=control)
+    got = _gaps(jnp.stack(xs, axis=1), jnp.asarray(targets), key, ref=ref,
+                dims=dims, control=control)
     mask = targets >= 0
     out = {"program": np.asarray(got[0])[mask]}
     if control:

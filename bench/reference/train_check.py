@@ -2,7 +2,9 @@
 
 The reference draws the same packed base and initial adapters from the
 seed as the program was given, and follows the program's first steps on
-the same batches in float32 at ``highest``: the loss of each step, the
+the same batches in float32 at ``highest``, through the reference module
+``ref`` that the configuration names (``bench.jobs.common.reference``
+lists what it provides): the loss of each step, the
 gradient of the first (clipped to a global norm as the optimizer clips
 it), and AdamW's updates, written out here as the optimizer's published
 rule.  Each sequence of a batch runs alone through a scan over layers that
@@ -18,43 +20,50 @@ import jax.numpy as jnp
 import numpy as np
 
 from bench import weights
-from bench.reference import dense
 
 LOSS_CHUNK = 1024
 
 
-def initial_lora(key, dims: dense.Dims, rank: int, dtype: str) -> dict:
-    """``{site: {"lora_a": (L, m, r), "lora_b": (L, n, r)}}`` in f32, as
-    the program was given them (drawn in ``dtype``)."""
+def initial_lora(key, sites: dict, n_layers: int, rank: int,
+                 dtype: str) -> dict:
+    """``{site: {"lora_a": (L, *lead, m, r), "lora_b": (L, *lead, n, r)}}``
+    in f32 for ``sites = {site: (lead, m, n)}``, as the program was given
+    them (drawn in ``dtype``): layer ``i`` of a site draws from
+    ``fold_in(leaf_key(key, "blocks.<site>"), i)``, split into one key per
+    expert where ``lead`` is ``(E,)``, and each adapter from that key
+    folded with 1, as ``bench.weights.make_params`` draws them."""
     out = {}
-    for site in dense.SITES:
-        m, n = dims.site_shape(site)
+    for site, (lead, m, n) in sites.items():
 
-        def one(i, site=site, m=m, n=n):
-            k = jax.random.fold_in(
-                jax.random.fold_in(weights.leaf_key(key, f"blocks.{site}"), i),
-                1)
-            ad = weights.adapter_layer(k, m, n, rank, getattr(jnp, dtype))
+        def pair(k, m=m, n=n):
+            ad = weights.adapter_layer(jax.random.fold_in(k, 1), m, n, rank,
+                                       getattr(jnp, dtype))
             return jax.tree.map(lambda a: a.astype(jnp.float32), ad)
-        out[site] = jax.lax.map(one, jnp.arange(dims.n_layers))
+
+        def one(i, site=site, lead=lead, pair=pair):
+            k = jax.random.fold_in(weights.leaf_key(key, f"blocks.{site}"), i)
+            if lead:
+                return jax.vmap(pair)(jax.random.split(k, lead[0]))
+            return pair(k)
+        out[site] = jax.lax.map(one, jnp.arange(n_layers))
     return out
 
 
-def _seq_loss(lora, key, tokens, labels, *, dims, prec):
+def _seq_loss(lora, key, tokens, labels, *, ref, dims, prec):
     """Summed next-token loss of one row, and its token count."""
-    x = dense.embedding(key, dims)[tokens][None]
+    x = ref.embedding(key, dims)[tokens][None]
 
     def body(x, inp):
         layer, lo = inp
-        base = dense.base_layer(key, dims, layer)
+        base = ref.base_layer(key, dims, layer)
         ad = {s: (lo[s]["lora_a"][None], lo[s]["lora_b"][None])
-              for s in dense.SITES}
-        return dense.block(prec, dims, x, base, ad), None
+              for s in lo}
+        return ref.block(prec, dims, x, base, ad), None
 
     body = jax.checkpoint(body)
     x, _ = jax.lax.scan(body, x, (jnp.arange(dims.n_layers), lora))
-    h = dense.rmsnorm(x[0])
-    head = dense.head(key, dims)
+    h = ref.final_norm(x[0])
+    head = ref.head(key, dims)
 
     def chunk(carry, inp):
         hc, lc = inp
@@ -70,19 +79,20 @@ def _seq_loss(lora, key, tokens, labels, *, dims, prec):
     return total
 
 
-@functools.partial(jax.jit, static_argnames=("dims", "prec"))
-def _seq_grad(lora, key, tokens, labels, *, dims, prec):
+@functools.partial(jax.jit, static_argnames=("ref", "dims", "prec"))
+def _seq_grad(lora, key, tokens, labels, *, ref, dims, prec):
     return jax.value_and_grad(_seq_loss)(lora, key, tokens, labels,
-                                         dims=dims, prec=prec)
+                                         ref=ref, dims=dims, prec=prec)
 
 
-def loss_and_grad(lora, key, batch, dims, prec=dense.EXACT):
+def loss_and_grad(lora, key, batch, ref, dims, prec):
     """Mean token loss of ``batch`` and its gradient, row by row."""
     tokens, labels = np.asarray(batch["tokens"]), np.asarray(batch["labels"])
     total, grad = 0.0, None
     for row in range(tokens.shape[0]):
         s, g = _seq_grad(lora, key, jnp.asarray(tokens[row]),
-                         jnp.asarray(labels[row]), dims=dims, prec=prec)
+                         jnp.asarray(labels[row]), ref=ref, dims=dims,
+                         prec=prec)
         total += float(s)
         grad = g if grad is None else jax.tree.map(jnp.add, grad, g)
     n = tokens.size

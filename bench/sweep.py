@@ -35,7 +35,6 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     from bench import harness
     from bench import traffic as gen
-    from bench.jobs.serve import Job
 
     bench = harness.spec()
     wl = harness.find(bench["workloads"], args.workload, "workload")
@@ -45,7 +44,7 @@ def main(argv=None) -> int:
     cell = harness.Cell(args.workload, harness.config_file(wl["config"]),
                         harness.traffic_file(wl["traffic"]), args.seed,
                         args.seconds, False)
-    job = Job(cell)
+    job = harness.job_class(cell.traffic["job"])(cell)
     job.setup()
     print(f"setup: seconds={time.perf_counter() - T_START:.3f}", flush=True)
     for rate in (float(r) for r in args.rates.split(",")):

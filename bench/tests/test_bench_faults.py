@@ -8,9 +8,8 @@ import time
 import bench_tiny
 import pytest
 
-from bench import faults, harness
-from bench.jobs import serve, train
-from bench.reference import dense
+from bench import harness
+from bench.jobs import common
 
 SERVE = "qwen3-1.7b.serve.multitenant"
 TRAIN = "qwen3-1.7b.finetune.packed"
@@ -26,94 +25,96 @@ def keep_cache_config():
     jax.config.update("jax_compilation_cache_dir", prev)
 
 
-@pytest.fixture(autouse=True)
-def smoke_limits(monkeypatch):
-    bench_tiny.patch_limits(monkeypatch)
+@pytest.fixture
+def serve(monkeypatch):
+    """The serve cell's job module, held to the smoke size."""
+    bench_tiny.patch(monkeypatch, SERVE)
+    return bench_tiny.job_module(SERVE)
 
 
-def _run(workload, seed, monkeypatch):
-    bench_tiny.patch_traffic(monkeypatch)
+@pytest.fixture
+def train(monkeypatch):
+    """The fine-tune cell's job module, held to the smoke size."""
+    bench_tiny.patch(monkeypatch, TRAIN)
+    return bench_tiny.job_module(TRAIN)
+
+
+def _run(workload, seed):
     return harness.run(workload, seed, 1.0, False,
                        t_start=time.perf_counter(), allow_cpu=True,
                        overrides=bench_tiny.config_for(workload),
                        out=io.StringIO())
 
 
-def _plant_serve(monkeypatch, name):
-    setup = serve.Job.setup
-
-    def broken(self):
-        setup(self)
-        faults.SERVE[name](self)
-    monkeypatch.setattr(serve.Job, "setup", broken)
+def _plant(monkeypatch, mod, name):
+    monkeypatch.setattr(mod.Job, "fault", staticmethod(mod.FAULTS[name]))
 
 
-def test_serve_altered_token_fails_the_check(monkeypatch):
-    _plant_serve(monkeypatch, "altered_token")
-    result = _run(SERVE, 21, monkeypatch)
+def test_serve_altered_token_fails_the_check(serve, monkeypatch):
+    _plant(monkeypatch, serve, "altered_token")
+    result = _run(SERVE, 21)
     assert result["correct"] is False
     c = result["checks"]["served_logit_gap"]
     assert c["value"] > c["limit"]
 
 
 @pytest.mark.parametrize("fault", ["wrong_slot", "zero_adapters"])
-def test_serve_adapter_gather_fault_fails_the_check(fault, monkeypatch):
-    _plant_serve(monkeypatch, fault)
-    result = _run(SERVE, 23, monkeypatch)
+def test_serve_adapter_gather_fault_fails_the_check(fault, serve,
+                                                    monkeypatch):
+    _plant(monkeypatch, serve, fault)
+    result = _run(SERVE, 23)
     assert result["correct"] is False
     c = result["checks"]["served_logit_gap"]
     assert c["value"] > c["limit"]
 
 
-def _serve(seed):
-    job = serve.Job(bench_tiny.cell(SERVE, seed))
+def _serve(mod, seed):
+    job = mod.Job(bench_tiny.cell(SERVE, seed))
     job.setup()
     job.window(harness.Tracer(False))
     job.release()
     return job
 
 
-def test_serve_control_reads_above_the_program():
-    job = _serve(22)
+def test_serve_control_reads_above_the_program(serve):
+    job = _serve(serve, 22)
     control = job.check(control=True)
     assert not harness.passed(control)
     g = job.gap_readings
-    assert g["program"] <= serve.GAP_LIMIT < g["control"]
+    assert g["program"] <= serve.LIMITS["served_logit_gap"] < g["control"]
 
 
-def _train(seed, fault=None):
-    job = train.Job(bench_tiny.cell(TRAIN, seed))
-    job.fault = fault
+def _train(mod, seed):
+    job = mod.Job(bench_tiny.cell(TRAIN, seed))
     job.setup()
     job.release()
     return job
 
 
-def _failed(readings):
-    return [k for k, v in train.LIMITS.items() if readings[k] > v]
+def _failed(mod, readings):
+    return [k for k, v in mod.LIMITS.items() if readings[k] > v]
 
 
-def test_train_sound_run_passes():
-    job = _train(31)
+def test_train_sound_run_passes(train):
+    job = _train(train, 31)
     assert harness.passed(job.check())
 
 
-def test_train_state_left_unchanged_fails_the_check():
-    job = _train(32)
-    ref = train.reference_run(job, dense.EXACT)
+def test_train_state_left_unchanged_fails_the_check(train):
+    job = _train(train, 32)
+    ref = train.reference_run(job, common.reference(job.cell.config).EXACT)
     got = train.program_run(job)
     got["after"] = {k: ref["p0"][k].copy() for k in got["after"]}
-    assert "update_norm_rel" in _failed(train.compare(got, ref))
+    assert "update_norm_rel" in _failed(train, train.compare(got, ref))
 
 
-def test_train_half_batch_fails_the_check(monkeypatch):
-    monkeypatch.setattr(train.Job, "fault",
-                        staticmethod(faults.train_half_batch))
-    result = _run(TRAIN, 33, monkeypatch)
+def test_train_half_batch_fails_the_check(train, monkeypatch):
+    _plant(monkeypatch, train, "half_batch")
+    result = _run(TRAIN, 33)
     assert result["correct"] is False
 
 
-def test_train_control_reads_above_the_program():
-    job = _train(34)
+def test_train_control_reads_above_the_program(train):
+    job = _train(train, 34)
     assert harness.passed(job.check())
     assert not harness.passed(job.check(control=True))

@@ -24,15 +24,10 @@ def keep_cache_config():
     jax.config.update("jax_compilation_cache_dir", prev)
 
 
-@pytest.fixture(autouse=True)
-def smoke_limits(monkeypatch):
-    bench_tiny.patch_limits(monkeypatch)
-
-
 @pytest.mark.parametrize("wl", SPEC["workloads"], ids=lambda w: w["name"])
 @pytest.mark.parametrize("trace", [False, True], ids=["e2e", "traced"])
 def test_job_runs_end_to_end_on_cpu(wl, trace, monkeypatch):
-    bench_tiny.patch_traffic(monkeypatch)
+    bench_tiny.patch(monkeypatch, wl["name"])
     out = io.StringIO()
     result = harness.run(wl["name"], 2**31 + 11, 1.0, trace,
                          t_start=time.perf_counter(), allow_cpu=True,

@@ -2,7 +2,7 @@
 import json
 import os
 
-import bench_tiny  # noqa: F401  (puts the repository on the path)
+import bench_tiny
 import pytest
 
 from bench import harness
@@ -36,6 +36,25 @@ def test_workload_finds_its_config_traffic_and_job(wl):
     assert callable(harness.job_class(mix["job"]))
     assert wl["chips"] in (1, 4)
     assert wl["name"] == f"{wl['config']}.{wl['traffic']}"
+
+
+@pytest.mark.parametrize("wl", SPEC["workloads"], ids=lambda w: w["name"])
+def test_workload_has_a_smoke_size_for_its_job(wl):
+    path = bench_tiny.path(wl["name"])
+    rel = os.path.relpath(path, harness.ROOT)
+    assert os.path.isfile(path), f"{wl['name']} has no smoke size: add {rel}"
+    tiny = bench_tiny.tiny(wl["name"])
+    assert {"why", "config", "traffic", "limits"} <= set(tiny), path
+    job = bench_tiny.job_module(wl["name"])
+    assert set(job.LIMITS) <= set(tiny["limits"]), path
+
+
+@pytest.mark.parametrize("wl", SPEC["workloads"], ids=lambda w: w["name"])
+def test_job_module_states_limits_faults_and_its_control(wl):
+    job = harness.job_module(harness.traffic_file(wl["traffic"])["job"])
+    assert job.LIMITS and all(v > 0 for v in job.LIMITS.values())
+    assert job.FAULTS and all(callable(f) for f in job.FAULTS.values())
+    assert callable(job.Job.control) and job.Job.fault is None
 
 
 @pytest.mark.parametrize("metric", SPEC["per_layer"], ids=lambda m: m["name"])

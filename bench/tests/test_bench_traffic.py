@@ -76,3 +76,17 @@ def test_train_batches_are_deterministic_and_every_row_differs():
     rows = [tuple(r) for r in np.asarray(a["tokens"])] + \
         [tuple(r) for r in np.asarray(c["tokens"])]
     assert len(set(rows)) == 4
+
+
+def test_uniform_train_batches_are_the_draws_they_were():
+    """A step's rows are the uniform draw of the step's own key, shifted
+    by one for the labels."""
+    import jax
+    import jax.numpy as jnp
+    seed, step = 2**31 + 77, 3
+    key = jax.random.fold_in(jax.random.PRNGKey(seed), 0x7261696E)
+    rows = np.asarray(jax.random.randint(jax.random.fold_in(key, step),
+                                         (2, 65), 0, 1000, jnp.int32))
+    got = gen.train_batch(seed, step, 2, 64, 1000)
+    np.testing.assert_array_equal(np.asarray(got["tokens"]), rows[:, :-1])
+    np.testing.assert_array_equal(np.asarray(got["labels"]), rows[:, 1:])
