@@ -168,6 +168,44 @@ def unpack_codes(packed: Array, bits: int, m: int) -> Array:
     return c.reshape(m, packed.shape[-1])
 
 
+def dequantize_packed_byte_order(packed: Array, scales: Array, zeros: Array,
+                                 bits: int, m: int,
+                                 dtype=jnp.float32) -> Array:
+    """``dequantize_int(unpack_codes(packed, bits, m), ...)`` for packed
+    INT codes ``(..., m / per, n)`` with groups ``(..., m / g, n)``, where
+    ``per`` codes share a byte, but with the ``m`` rows in byte order:
+    first the rows whose codes sit in each byte's lowest bits (``0, per,
+    2 per, ...``), then the next bits' (``1, per + 1, ...``), and so on.
+    Each part dequantizes straight from the packed bytes, with no
+    interleave of rows: a product with the weight takes its input in the
+    same order (:func:`byte_order`)."""
+    per = {2: 4, 4: 2}.get(bits, 1)
+    *lead, rows, n = packed.shape
+    group = m // scales.shape[-2]
+
+    def spread(a):          # (..., m/g, n) -> one part's (..., m/per, n)
+        a = jnp.expand_dims(a, -2)
+        return jnp.broadcast_to(a, (*lead, m // group, group // per, n)
+                                ).reshape(*lead, rows, n)
+
+    scales, zeros = spread(scales), spread(zeros)
+    mask = jnp.uint8(2**bits - 1)
+    parts = [((packed >> (bits * j)) & mask) if per > 1 else packed
+             for j in range(per)]
+    return jnp.concatenate([((c.astype(jnp.float32) - zeros) * scales)
+                            .astype(dtype) for c in parts], axis=-2)
+
+
+def byte_order(x: Array, bits: int) -> Array:
+    """``x``'s last axis, of ``m`` features, in the row order of
+    :func:`dequantize_packed_byte_order`, so that ``byte_order(x) @ w``
+    with that weight is ``x @ dequantize_int(...)``."""
+    per = {2: 4, 4: 2}.get(bits, 1)
+    *lead, m = x.shape
+    return jnp.swapaxes(x.reshape(*lead, m // per, per), -1, -2
+                        ).reshape(*lead, m)
+
+
 # ----------------------------- NF4 -----------------------------------------
 
 

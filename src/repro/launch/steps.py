@@ -15,8 +15,9 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.pipeline import quantized_param_shapes
 from repro.models.parallel import PContext
-from repro.models.transformer import (ModelConfig, decode_step, forward,
-                                      init_decode_cache, init_params, loss_fn)
+from repro.models.transformer import (ModelConfig, aux_zero, decode_step,
+                                      forward, init_decode_cache, init_params,
+                                      lm_logits, loss_fn)
 from repro.optim import (OptConfig, adamw_init, adamw_update, make_schedule,
                          merge_params, partition_params, trainable_mask)
 from repro.launch.shardings import cache_specs, param_specs
@@ -89,16 +90,20 @@ def make_train_step(cfg: ModelConfig, ocfg: OptConfig, pctx: PContext,
             zero_g = jax.tree.map(
                 lambda p: jnp.zeros(p.shape, jnp.float32), state["train"])
             zeros = (zero_g, {"l": jnp.zeros(()), "ce": jnp.zeros(()),
-                              "aux": jnp.zeros(())})
+                              "aux": aux_zero(cfg)})
             (grads, sums), _ = jax.lax.scan(body, zeros, mb)
             grads = jax.tree.map(lambda g: g / k, grads)
-            loss, ce, aux = sums["l"] / k, sums["ce"] / k, sums["aux"] / k
+            # each aux term, the MoE load included, is the microbatches' mean
+            loss, ce = sums["l"] / k, sums["ce"] / k
+            aux = jax.tree.map(lambda a: a / k, sums["aux"])
         else:
             (loss, (ce, aux)), grads = jax.value_and_grad(
                 loss_of, has_aux=True)(state["train"], batch)
         new_tp, new_opt, m = adamw_update(grads, state["opt"], state["train"],
                                           ocfg, schedule)
-        metrics = {"loss": loss, "ce": ce, "aux": aux, **m}
+        metrics = {"loss": loss, "ce": ce, "aux": aux["balance"], **m}
+        if "load_max" in aux:
+            metrics["moe_load_max"] = aux["load_max"]
         return {"train": new_tp, "frozen": state["frozen"],
                 "opt": new_opt}, metrics
 
@@ -112,11 +117,9 @@ def make_prefill_step(cfg: ModelConfig, pctx: PContext,
     prefill feeds a decode loop; §Perf lever)."""
     def prefill(params, batch):
         if last_only:
-            from repro.models.modules import lm_head_apply
             hidden, _ = forward(params, cfg, batch, pctx=pctx,
                                 return_hidden=True)
-            head = params.get("head", params["embed"])
-            return lm_head_apply(head, hidden[:, -1:, :])
+            return lm_logits(params, cfg, hidden[:, -1:, :])
         logits, _ = forward(params, cfg, batch, pctx=pctx)
         return logits
 

@@ -266,6 +266,9 @@ def _run(args) -> int:
         dt = time.time() - t0
         step_hist.observe(dt)
         step_count.inc()
+        if "moe_load_max" in metrics:
+            obs_metrics.gauge(obs_names.TRAIN_MOE_LOAD_MAX).set(
+                float(metrics["moe_load_max"]))
         if len(times) >= 5:
             med = statistics.median(times[-50:])
             if dt > args.straggler_factor * med:

@@ -28,6 +28,10 @@ class AttnConfig:
     sliding_window: int | None = None   # None = full attention
     causal: bool = True
     bias: bool = False                  # qwen1.5-style qkv bias
+    # qk-norm over each head's ``hd`` ("head", qwen3) or over the whole
+    # q and k projections before they are split into heads ("proj", OLMoE)
+    qk_norm_span: str = "head"
+    norm_eps: float = 1e-6
 
     @property
     def hd(self) -> int:
@@ -68,24 +72,39 @@ def attn_init(key, cfg: AttnConfig, *, dtype=jnp.bfloat16,
                          lora_rank=lora_rank),
     }
     if cfg.qk_norm:
-        p["q_norm"] = rmsnorm_init(hd, dtype)
-        p["k_norm"] = rmsnorm_init(hd, dtype)
+        proj = cfg.qk_norm_span == "proj"
+        p["q_norm"] = rmsnorm_init(cfg.n_heads * hd if proj else hd, dtype)
+        p["k_norm"] = rmsnorm_init(cfg.n_kv_heads * hd if proj else hd, dtype)
     return p
+
+
+def _qk(p, cfg: AttnConfig, x: Array, kv_src: Array, qspec: QSpec | None):
+    """q (B, Sq, H, hd) from ``x`` and k, v (B, Sk, Hkv, hd) from
+    ``kv_src``, qk-normed as ``cfg`` says."""
+    B, hd = x.shape[0], cfg.hd
+    whole = cfg.qk_norm and cfg.qk_norm_span == "proj"
+
+    def project(name, src, heads):
+        with scope(name):
+            y = linear_apply(p[name], src, qspec)
+            if name == "v" or not whole:
+                return y.reshape(B, src.shape[1], heads, hd)
+        # OLMoE: one norm over the whole projection, before the split
+        y = rmsnorm_apply(p[f"{name}_norm"], y, cfg.norm_eps)
+        return y.reshape(B, src.shape[1], heads, hd)
+
+    q = project("q", x, cfg.n_heads)
+    k = project("k", kv_src, cfg.n_kv_heads)
+    v = project("v", kv_src, cfg.n_kv_heads)
+    if cfg.qk_norm and not whole:
+        q = rmsnorm_apply(p["q_norm"], q, cfg.norm_eps)
+        k = rmsnorm_apply(p["k_norm"], k, cfg.norm_eps)
+    return q, k, v
 
 
 def _project_qkv(p, cfg: AttnConfig, x: Array, positions: Array,
                  qspec: QSpec | None, rope: bool = True):
-    B, S, _ = x.shape
-    hd = cfg.hd
-    with scope("q"):
-        q = linear_apply(p["q"], x, qspec).reshape(B, S, cfg.n_heads, hd)
-    with scope("k"):
-        k = linear_apply(p["k"], x, qspec).reshape(B, S, cfg.n_kv_heads, hd)
-    with scope("v"):
-        v = linear_apply(p["v"], x, qspec).reshape(B, S, cfg.n_kv_heads, hd)
-    if cfg.qk_norm:
-        q = rmsnorm_apply(p["q_norm"], q)
-        k = rmsnorm_apply(p["k_norm"], k)
+    q, k, v = _qk(p, cfg, x, x, qspec)
     if rope:
         q = apply_rope(q, positions, cfg.rope_theta)
         k = apply_rope(k, positions, cfg.rope_theta)
@@ -214,17 +233,7 @@ def cross_attn_apply(p, cfg: AttnConfig, x: Array, kv_src: Array, *,
                      qspec: QSpec | None = None) -> Array:
     """Encoder-decoder cross attention (no RoPE on cross path)."""
     B, Sq, _ = x.shape
-    Sk = kv_src.shape[1]
-    hd = cfg.hd
-    with scope("q"):
-        q = linear_apply(p["q"], x, qspec).reshape(B, Sq, cfg.n_heads, hd)
-    with scope("k"):
-        k = linear_apply(p["k"], kv_src, qspec).reshape(B, Sk, cfg.n_kv_heads, hd)
-    with scope("v"):
-        v = linear_apply(p["v"], kv_src, qspec).reshape(B, Sk, cfg.n_kv_heads, hd)
-    if cfg.qk_norm:
-        q = rmsnorm_apply(p["q_norm"], q)
-        k = rmsnorm_apply(p["k_norm"], k)
+    q, k, v = _qk(p, cfg, x, kv_src, qspec)
     out = _sdpa(q, k, v, None)
     with scope("o"):
         return linear_apply(p["o"], out.reshape(B, Sq, -1).astype(x.dtype), qspec)

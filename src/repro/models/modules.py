@@ -157,10 +157,10 @@ def embedding_apply(p: dict, tokens: Array) -> Array:
         return jnp.take(p["w"], tokens, axis=0)
 
 
-def lm_head_apply(p: dict, x: Array) -> Array:
-    """Logits. ``p`` may be a tied embedding ({'w': (V, d)}) or a linear."""
+def lm_head_apply(p: dict, x: Array, *, tied: bool) -> Array:
+    """Logits. ``p`` is the embedding ({'w': (V, d)}) where ``tied``, else
+    the head linear ({'w': (d, V)}); the caller, who knows the model's
+    ``tie_embeddings``, says which (a square ``w`` cannot tell)."""
     with scope("lm_head"):
         w = p["w"].astype(x.dtype)
-        if w.shape[0] != x.shape[-1]:          # tied embedding (V, d)
-            return x @ w.T
-        return x @ w
+        return x @ w.T if tied else x @ w

@@ -44,14 +44,19 @@ class ModelConfig:
     d_ff: int = 0
     head_dim: int | None = None
     qk_norm: bool = False
+    qk_norm_span: str = "head"    # head: per head (qwen3) | proj: whole q/k
     attn_bias: bool = False
     rope_theta: float = 1e6
+    rms_norm_eps: float = 1e-6
     tie_embeddings: bool = False
     # MoE
     n_experts: int = 0
     top_k: int = 0
     d_ff_expert: int = 0
-    capacity_factor: float = 1.25
+    # None: dropless, grouped matmuls over every routed row, no drops
+    capacity_factor: float | None = 1.25
+    norm_topk: bool = True        # renormalize the top-k router weights
+    router_aux_weight: float = 0.01   # load-balance term's weight in loss
     # SSM
     ssm_state: int = 0
     ssm_head_dim: int = 64
@@ -81,11 +86,13 @@ class ModelConfig:
     def attn_cfg(self, causal=True, window=None) -> AttnConfig:
         return AttnConfig(self.d_model, self.n_heads, self.n_kv_heads,
                           self.head_dim, self.qk_norm, self.rope_theta,
-                          window, causal, self.attn_bias)
+                          window, causal, self.attn_bias, self.qk_norm_span,
+                          self.rms_norm_eps)
 
     def moe_cfg(self) -> MoEConfig:
         return MoEConfig(self.n_experts, self.top_k, self.d_model,
-                         self.d_ff_expert, self.capacity_factor)
+                         self.d_ff_expert, self.capacity_factor,
+                         self.norm_topk)
 
     def ssm_cfg(self) -> SSMConfig:
         return SSMConfig(self.d_model, self.ssm_state, self.ssm_head_dim,
@@ -129,35 +136,50 @@ def _block_init(key, cfg: ModelConfig) -> dict:
     raise ValueError(cfg.family)
 
 
+def aux_zero(cfg: ModelConfig) -> dict:
+    """What the layer stack gathers from its layers: the load-balance loss
+    term (summed over layers) and, in an MoE model, the busiest expert's
+    routed rows over the mean (the largest over layers)."""
+    aux = {"balance": jnp.zeros((), jnp.float32)}
+    if cfg.family == "moe":
+        aux["load_max"] = jnp.zeros((), jnp.float32)
+    return aux
+
+
+def _aux_add(a: dict, b: dict) -> dict:
+    return {k: jnp.maximum(a[k], b[k]) if k == "load_max" else a[k] + b[k]
+            for k in a}
+
+
 def _block_apply(p, cfg: ModelConfig, x: Array, *, pctx: PContext,
-                 window: int | None = None) -> tuple[Array, Array]:
-    """Returns (y, aux_loss)."""
-    aux = jnp.zeros((), jnp.float32)
+                 window: int | None = None) -> tuple[Array, dict]:
+    """Returns (y, aux) as :func:`aux_zero` lays it out."""
+    aux = aux_zero(cfg)
     q = cfg.quant
     chunk = cfg.attn_chunk or None
     if cfg.family in ("dense", "encdec"):
         with scope("attn"):
             y = attn_apply(p["attn"], cfg.attn_cfg(window=window),
-                           rmsnorm_apply(p["ln1"], x), qspec=q,
+                           rmsnorm_apply(p["ln1"], x, cfg.rms_norm_eps), qspec=q,
                            q_chunk=chunk)
             x = _seq_shard(cfg, x + _tag_tp_out(cfg, y), pctx)
         with scope("mlp"):
-            y = swiglu_apply(p["mlp"], rmsnorm_apply(p["ln2"], x), q)
+            y = swiglu_apply(p["mlp"], rmsnorm_apply(p["ln2"], x, cfg.rms_norm_eps), q)
             x = _seq_shard(cfg, x + _tag_tp_out(cfg, y), pctx)
     elif cfg.family == "moe":
         with scope("attn"):
             y = attn_apply(p["attn"], cfg.attn_cfg(window=window),
-                           rmsnorm_apply(p["ln1"], x), qspec=q,
+                           rmsnorm_apply(p["ln1"], x, cfg.rms_norm_eps), qspec=q,
                            q_chunk=chunk)
             x = _seq_shard(cfg, x + _tag_tp_out(cfg, y), pctx)
         with scope("moe"):
             y, aux = moe_apply(p["moe"], cfg.moe_cfg(),
-                               rmsnorm_apply(p["ln2"], x), qspec=q, pctx=pctx)
+                               rmsnorm_apply(p["ln2"], x, cfg.rms_norm_eps), qspec=q, pctx=pctx)
             x = _seq_shard(cfg, x + _tag_tp_out(cfg, y), pctx)
     elif cfg.family in ("ssm", "hybrid"):
         with scope("mamba"):
             y = mamba_apply(p["mamba"], cfg.ssm_cfg(),
-                            rmsnorm_apply(p["norm"], x), qspec=q)
+                            rmsnorm_apply(p["norm"], x, cfg.rms_norm_eps), qspec=q)
             x = _seq_shard(cfg, x + _tag_tp_out(cfg, y), pctx)
     return x, aux
 
@@ -210,9 +232,9 @@ def _shared_block_apply(p, cfg: ModelConfig, x: Array, site: Array, *,
     blk = _with_site_lora(p["block"], p["site_lora"], site)
     with scope("shared.attn"):
         x = x + attn_apply(blk["attn"], cfg.attn_cfg(window=window),
-                           rmsnorm_apply(blk["ln1"], x), qspec=cfg.quant)
+                           rmsnorm_apply(blk["ln1"], x, cfg.rms_norm_eps), qspec=cfg.quant)
     with scope("shared.mlp"):
-        x = x + swiglu_apply(blk["mlp"], rmsnorm_apply(blk["ln2"], x), cfg.quant)
+        x = x + swiglu_apply(blk["mlp"], rmsnorm_apply(blk["ln2"], x, cfg.rms_norm_eps), cfg.quant)
     return x
 
 
@@ -310,14 +332,14 @@ def _segment_blocks(blocks, n_layers: int, every: int, n_sites: int):
 
 def _run_stack(blocks, cfg: ModelConfig, x: Array, *, pctx: PContext,
                window: int | None = None, shared: dict | None = None):
-    """Scan (or loop) the block stack. Returns (x, total_aux)."""
+    """Scan (or loop) the block stack. Returns (x, aux) over all layers."""
     every = cfg.hybrid_attn_every
-    zero = jnp.zeros((), jnp.float32)
+    zero = aux_zero(cfg)
 
     def body_fn(carry, bp):
         x, aux = carry
         y, a = _block_apply(bp, cfg, x, pctx=pctx, window=window)
-        return (y, aux + a), None
+        return (y, _aux_add(aux, a)), None
 
     pol = _remat_policy(cfg)
 
@@ -369,7 +391,7 @@ def _run_stack(blocks, cfg: ModelConfig, x: Array, *, pctx: PContext,
                 x, a = block_fn(blocks[i], x)
             else:
                 x, a = _block_apply(blocks[i], cfg, x, pctx=pctx, window=window)
-            aux = aux + a
+            aux = _aux_add(aux, a)
         if shared is not None and (int(i) + 1) % every == 0:
             site = (int(i) + 1) // every - 1
             if site < cfg.n_hybrid_sites:
@@ -397,13 +419,12 @@ def forward(params: dict, cfg: ModelConfig, batch: dict, *,
     shared = params.get("shared")
     x, aux = _run_stack(params["blocks"], cfg, x, pctx=pctx, window=window,
                         shared=shared)
-    x = rmsnorm_apply(params["final_norm"], x)
+    x = rmsnorm_apply(params["final_norm"], x, cfg.rms_norm_eps)
     if cfg.frontend == "vision" and "prefix_embeds" in batch:
         x = x[:, batch["prefix_embeds"].shape[1]:, :]
     if return_hidden:
         return x, aux
-    head = params.get("head", params["embed"])
-    return lm_head_apply(head, x), aux
+    return lm_logits(params, cfg, x), aux
 
 
 def _forward_encdec(params, cfg: ModelConfig, batch, *, pctx: PContext,
@@ -414,9 +435,9 @@ def _forward_encdec(params, cfg: ModelConfig, batch, *, pctx: PContext,
         x, _ = carry
         with scope("attn"):
             x = x + attn_apply(bp["attn"], cfg.attn_cfg(causal=False),
-                               rmsnorm_apply(bp["ln1"], x), qspec=cfg.quant)
+                               rmsnorm_apply(bp["ln1"], x, cfg.rms_norm_eps), qspec=cfg.quant)
         with scope("mlp"):
-            x = x + swiglu_apply(bp["mlp"], rmsnorm_apply(bp["ln2"], x), cfg.quant)
+            x = x + swiglu_apply(bp["mlp"], rmsnorm_apply(bp["ln2"], x, cfg.rms_norm_eps), cfg.quant)
         return (x, jnp.zeros((), jnp.float32)), None
 
     if cfg.scan_layers:
@@ -430,7 +451,7 @@ def _forward_encdec(params, cfg: ModelConfig, batch, *, pctx: PContext,
             with scope(f"enc_blocks.{i}"):
                 (enc_x, _), _ = enc_body((enc_x, jnp.zeros((), jnp.float32)),
                                          params["enc_blocks"][i])
-    enc_out = rmsnorm_apply(params["enc_norm"], enc_x)
+    enc_out = rmsnorm_apply(params["enc_norm"], enc_x, cfg.rms_norm_eps)
 
     x = embedding_apply(params["embed"], batch["tokens"]).astype(cfg.dtype)
 
@@ -441,27 +462,34 @@ def _forward_encdec(params, cfg: ModelConfig, batch, *, pctx: PContext,
                             pctx=pctx)
         with scope("cross"):
             y = y + cross_attn_apply(cp["xattn"], cfg.attn_cfg(causal=False),
-                                     rmsnorm_apply(cp["ln"], y), enc_out,
+                                     rmsnorm_apply(cp["ln"], y, cfg.rms_norm_eps), enc_out,
                                      qspec=cfg.quant)
-        return (y, aux + a), None
+        return (y, _aux_add(aux, a)), None
 
     if cfg.scan_layers:
         body = jax.checkpoint(dec_body, policy=_remat_policy(cfg) or
                               jax.checkpoint_policies.nothing_saveable,
                               prevent_cse=False)
-        (x, aux), _ = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)),
+        (x, aux), _ = jax.lax.scan(body, (x, aux_zero(cfg)),
                                    (params["dec_blocks"], params["cross"]))
     else:
-        aux = jnp.zeros((), jnp.float32)
+        aux = aux_zero(cfg)
         for i in sorted(params["dec_blocks"], key=int):
             with scope(f"dec_blocks.{i}"):
                 (x, aux), _ = dec_body(
                     (x, aux), (params["dec_blocks"][i], params["cross"][i]))
-    x = rmsnorm_apply(params["final_norm"], x)
+    x = rmsnorm_apply(params["final_norm"], x, cfg.rms_norm_eps)
     if return_hidden:
         return x, aux
-    head = params.get("head", params["embed"])
-    return lm_head_apply(head, x), aux
+    return lm_logits(params, cfg, x), aux
+
+
+def lm_logits(params: dict, cfg: ModelConfig, x: Array) -> Array:
+    """Logits of the hidden states ``x``: through the embedding where the
+    model ties it to the head, else through the head."""
+    if cfg.tie_embeddings:
+        return lm_head_apply(params["embed"], x, tied=True)
+    return lm_head_apply(params["head"], x, tied=False)
 
 
 def _ce(logits: Array, labels: Array):
@@ -482,13 +510,12 @@ def loss_fn(params: dict, cfg: ModelConfig, batch: dict, *,
         # UNROLLED (not lax.map) so cost_analysis FLOPs stay exact.
         hidden, aux = forward(params, cfg, batch, pctx=pctx, window=window,
                               return_hidden=True)
-        head = params.get("head", params["embed"])
         B, S, D = hidden.shape
         nb = S // C
         tot_s = jnp.zeros((), jnp.float32)
         tot_c = jnp.zeros((), jnp.float32)
         for i in range(nb):
-            s, c = _ce(lm_head_apply(head, hidden[:, i * C:(i + 1) * C]),
+            s, c = _ce(lm_logits(params, cfg, hidden[:, i * C:(i + 1) * C]),
                        labels[:, i * C:(i + 1) * C])
             tot_s += s
             tot_c += c
@@ -497,7 +524,7 @@ def loss_fn(params: dict, cfg: ModelConfig, batch: dict, *,
         logits, aux = forward(params, cfg, batch, pctx=pctx, window=window)
         s, c = _ce(logits, labels)
         loss = -s / jnp.maximum(c, 1.0)
-    return loss + 0.01 * aux, (loss, aux)
+    return loss + cfg.router_aux_weight * aux["balance"], (loss, aux)
 
 
 # ---------------------------------------------------------------------------
@@ -570,7 +597,7 @@ def decode_step_rows(params: dict, cfg: ModelConfig, cache: dict,
             x = carry
             bp, k_l, v_l, extras = inp
             with scope("attn"):
-                h = rmsnorm_apply(bp["ln1"], x)
+                h = rmsnorm_apply(bp["ln1"], x, cfg.rms_norm_eps)
                 y, new_kv, (k_row, v_row) = attn_decode(
                     bp["attn"], acfg, h, {"k": k_l, "v": v_l, "idx": idx},
                     qspec=q)
@@ -580,15 +607,15 @@ def decode_step_rows(params: dict, cfg: ModelConfig, cache: dict,
                 with scope("cross"):
                     x = x + cross_attn_apply(
                         cp["xattn"], cfg.attn_cfg(causal=False),
-                        rmsnorm_apply(cp["ln"], x), cache["enc_out"], qspec=q)
+                        rmsnorm_apply(cp["ln"], x, cfg.rms_norm_eps), cache["enc_out"], qspec=q)
             if cfg.family == "moe":
                 with scope("moe"):
                     y2, _ = moe_apply(bp["moe"], cfg.moe_cfg(),
-                                      rmsnorm_apply(bp["ln2"], x), qspec=q,
+                                      rmsnorm_apply(bp["ln2"], x, cfg.rms_norm_eps), qspec=q,
                                       pctx=pctx)
             else:
                 with scope("mlp"):
-                    y2 = swiglu_apply(bp["mlp"], rmsnorm_apply(bp["ln2"], x),
+                    y2 = swiglu_apply(bp["mlp"], rmsnorm_apply(bp["ln2"], x, cfg.rms_norm_eps),
                                       q)
             return x + y2, (new_kv["k"], new_kv["v"], k_row, v_row)
 
@@ -621,7 +648,7 @@ def decode_step_rows(params: dict, cfg: ModelConfig, cache: dict,
             x = carry
             bp, cx_l, cb_l, st_l = inp
             with scope("mamba"):
-                h = rmsnorm_apply(bp["norm"], x)
+                h = rmsnorm_apply(bp["norm"], x, cfg.rms_norm_eps)
                 y, nc = mamba_decode(bp["mamba"], scfg, h,
                                      {"conv_x": cx_l, "conv_bc": cb_l,
                                       "state": st_l}, qspec=q)
@@ -646,13 +673,13 @@ def decode_step_rows(params: dict, cfg: ModelConfig, cache: dict,
                         body, x, (bseg, cx_seg, cb_seg, st_seg))
                     blk = _with_site_lora(shared["block"], shared["site_lora"],
                                           site)
-                    h2 = rmsnorm_apply(blk["ln1"], x)
+                    h2 = rmsnorm_apply(blk["ln1"], x, cfg.rms_norm_eps)
                     y2, nkv, _ = attn_decode(
                         blk["attn"], acfg, h2,
                         {"k": kv_k, "v": kv_v, "idx": idx}, qspec=q)
                     x = x + y2
                     x = x + swiglu_apply(blk["mlp"],
-                                         rmsnorm_apply(blk["ln2"], x), q)
+                                         rmsnorm_apply(blk["ln2"], x, cfg.rms_norm_eps), q)
                     return x, (CX, CB, S2, nkv["k"], nkv["v"])
 
                 x, (CXs, CBs, Ss, NK, NV) = jax.lax.scan(
@@ -690,14 +717,14 @@ def decode_step_rows(params: dict, cfg: ModelConfig, cache: dict,
                         blk = _with_site_lora(shared["block"], shared["site_lora"],
                                               jnp.int32(site))
                         skv = cache["shared_kv"]
-                        h2 = rmsnorm_apply(blk["ln1"], x)
+                        h2 = rmsnorm_apply(blk["ln1"], x, cfg.rms_norm_eps)
                         y2, nkv, _ = attn_decode(
                             blk["attn"], acfg, h2,
                             {"k": skv["k"][site], "v": skv["v"][site],
                              "idx": idx}, qspec=q)
                         x = x + y2
                         x = x + swiglu_apply(blk["mlp"],
-                                             rmsnorm_apply(blk["ln2"], x), q)
+                                             rmsnorm_apply(blk["ln2"], x, cfg.rms_norm_eps), q)
                         skv["k"] = skv["k"].at[site].set(nkv["k"])
                         skv["v"] = skv["v"].at[site].set(nkv["v"])
             new_cache = dict(cache, conv_x=jnp.stack(CXs), conv_bc=jnp.stack(CBs),
@@ -705,7 +732,6 @@ def decode_step_rows(params: dict, cfg: ModelConfig, cache: dict,
     else:
         raise ValueError(cfg.family)
 
-    x = rmsnorm_apply(params["final_norm"], x)
-    head = params.get("head", params["embed"])
-    logits = lm_head_apply(head, x)[:, 0, :]
+    x = rmsnorm_apply(params["final_norm"], x, cfg.rms_norm_eps)
+    logits = lm_logits(params, cfg, x)[:, 0, :]
     return logits, new_cache, rows

@@ -64,6 +64,9 @@ SERVE_KV_OCCUPANCY = "serve.kv_occupancy"
 
 TRAIN_STEPS = "train.steps"
 TRAIN_STEP_TIME = "train.step_s"
+# MoE train step: the busiest expert's routed rows over the mean, the
+# largest over layers (the step's ``moe_load_max``)
+TRAIN_MOE_LOAD_MAX = "train.moe_load_max"
 
 # -- layer scopes ----------------------------------------------------------
 
@@ -74,6 +77,9 @@ TRAIN_STEP_TIME = "train.step_s"
 SCOPES = (
     # layer kinds
     "embed", "attn", "mlp", "moe", "mamba", "cross", "shared", "lm_head",
+    # inside moe: the router, rows sorted to their experts, and the
+    # weighted rows gathered back to their tokens
+    "route", "dispatch", "combine",
     # projections
     "q", "k", "v", "o", "gate", "up", "down",
     "z_proj", "x_proj", "bc_proj", "dt_proj", "out_proj",
@@ -104,6 +110,7 @@ COUNTERS = (
 GAUGES = (
     SERVE_KV_PAGES_IN_USE,
     SERVE_KV_PAGES_TOTAL,
+    TRAIN_MOE_LOAD_MAX,
 )
 
 _LATENCY_EDGES = (0.0005, 0.001, 0.003, 0.01, 0.03, 0.1,

@@ -64,13 +64,19 @@ class GramStore:
     """Accumulates per-layer Gram matrices H = sum_batches X^T X (f32).
 
     ``keep_leading=True`` (MoE expert buffers shaped (E, C, D)) keeps the
-    leading dim and accumulates one Gram per expert: H (E, D, D)."""
+    leading dim and accumulates one Gram per expert: H (E, D, D).
+    ``groups`` (the dropless MoE layer's rows ``(N, D)``, sorted by expert
+    with ``groups[e]`` rows for expert ``e``) gives the same ``(E, D, D)``,
+    each expert's Gram from the rows routed to it."""
 
     def __init__(self) -> None:
         self.grams: dict[str, np.ndarray] = {}
         self.counts: dict[str, int] = {}
 
-    def add(self, path: str, x: jax.Array, keep_leading: bool = False) -> None:
+    def add(self, path: str, x: jax.Array, keep_leading: bool = False,
+            groups: jax.Array | None = None) -> None:
+        if groups is not None:
+            x, keep_leading = _grouped_rows(x, groups), True
         if keep_leading:
             x3 = jnp.asarray(x, jnp.float32)
             x3 = x3.reshape(x3.shape[0], -1, x3.shape[-1])
@@ -114,6 +120,18 @@ class GramStore:
         return all(np.isfinite(g).all() for g in self.grams.values())
 
 
+def _grouped_rows(x: jax.Array, groups: jax.Array) -> jax.Array:
+    """Rows ``x (N, D)`` sorted by group, ``groups[e]`` of them in group
+    ``e`` (rows past the groups belong to none), laid out as ``(E, C, D)``
+    with ``C`` the largest group, zero-padded."""
+    sizes = np.asarray(groups)
+    group = np.repeat(np.arange(len(sizes)), sizes)
+    pos = np.arange(len(group)) - (np.cumsum(sizes) - sizes)[group]
+    out = jnp.zeros((len(sizes), max(int(sizes.max()), 1), x.shape[-1]),
+                    jnp.float32)
+    return out.at[group, pos].set(jnp.asarray(x[:len(group)], jnp.float32))
+
+
 def _capture_store() -> GramStore | None:
     return getattr(_state, "capture", None)
 
@@ -132,13 +150,14 @@ def is_capturing() -> bool:
     return _capture_store() is not None
 
 
-def record_activation(path: str, x: jax.Array, keep_leading: bool = False) -> None:
+def record_activation(path: str, x: jax.Array, keep_leading: bool = False,
+                      groups: jax.Array | None = None) -> None:
     store = _capture_store()
     if store is None:
         return
     if isinstance(x, jax.core.Tracer):  # under jit: capture is eager-only
         return
-    store.add(path, x, keep_leading=keep_leading)
+    store.add(path, x, keep_leading=keep_leading, groups=groups)
 
 
 # ---------------------------------------------------------------------------

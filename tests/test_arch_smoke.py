@@ -74,6 +74,10 @@ def test_full_configs_match_assignment():
     c = get_config("olmoe-1b-7b")
     assert (c.n_layers, c.d_model, c.n_experts, c.top_k, c.vocab) == \
         (16, 2048, 64, 8, 50304)
+    assert (c.n_heads, c.n_kv_heads, c.head_dim, c.d_ff_expert,
+            c.rope_theta, c.rms_norm_eps) == (16, 16, 128, 1024, 1e4, 1e-5)
+    assert c.qk_norm and c.qk_norm_span == "proj" and not c.tie_embeddings
+    assert c.capacity_factor is None and not c.norm_topk and c.router_aux_weight == 0.0
     c = get_config("qwen3-4b")
     assert (c.n_layers, c.d_model, c.n_heads, c.n_kv_heads, c.d_ff) == \
         (36, 2560, 32, 8, 9728) and c.qk_norm

@@ -68,7 +68,8 @@ def test_moe_shard_map_matches_local():
                                    atol=2e-5)
         # aux is pmean of per-shard load-balance stats (mean-of-products),
         # not the global-batch statistic: close but not bit-equal
-        np.testing.assert_allclose(float(aux), float(aux_ref), rtol=5e-2)
+        np.testing.assert_allclose(float(aux["balance"]),
+                                   float(aux_ref["balance"]), rtol=5e-2)
         print("moe EP == local")
     """)
 

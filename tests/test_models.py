@@ -103,7 +103,7 @@ def test_moe_capacity_drops_and_weights():
     p = moe_init(jax.random.PRNGKey(4), cfg, dtype=jnp.float32)
     x = jnp.asarray(RNG.normal(size=(2, 8, 16)), jnp.float32)
     y, aux = moe_apply(p, cfg, x)
-    assert y.shape == x.shape and bool(jnp.isfinite(aux))
+    assert y.shape == x.shape and bool(jnp.isfinite(aux["balance"]))
     # generous capacity: every token hits k experts; tiny capacity drops some
     cfg_tight = MoEConfig(n_experts=4, top_k=2, d_model=16, d_ff=32,
                           capacity_factor=0.5)
@@ -163,3 +163,20 @@ def test_model_decode_matches_forward(family, kw):
     np.testing.assert_allclose(np.asarray(logits_dec),
                                np.asarray(logits_full), atol=2e-3,
                                err_msg=family)
+
+
+@pytest.mark.parametrize("tied", [True, False], ids=["tied", "untied"])
+def test_head_as_wide_as_the_model_is_told_tied_from_untied(tied):
+    """With as many vocabulary rows as model widths the head's weight is
+    square, so only the model's ``tie_embeddings`` says whether it is the
+    embedding (used transposed) or a head linear."""
+    cfg = ModelConfig(name="t", family="dense", n_layers=1, d_model=32,
+                      vocab=32, n_heads=4, n_kv_heads=2, d_ff=64,
+                      tie_embeddings=tied, dtype=jnp.float32)
+    p = init_params(jax.random.PRNGKey(6), cfg)
+    toks = jnp.asarray(RNG.integers(0, 32, (2, 8)), jnp.int32)
+    hidden, _ = forward(p, cfg, {"tokens": toks}, return_hidden=True)
+    logits, _ = forward(p, cfg, {"tokens": toks})
+    want = hidden @ (p["embed"]["w"].T if tied else p["head"]["w"])
+    np.testing.assert_allclose(np.asarray(logits), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)

@@ -4,8 +4,10 @@ import numpy as np
 import pytest
 from tests._hypothesis_compat import given, settings, st
 
-from repro.core.quantizer import (NF4_LEVELS, QuantConfig, dequantize_int,
-                                  dequantize_nf4, pack_codes, quant_params,
+from repro.core.quantizer import (NF4_LEVELS, QuantConfig, byte_order,
+                                  dequantize_int, dequantize_nf4,
+                                  dequantize_packed_byte_order,
+                                  pack_codes, quant_params,
                                   quantize_int, quantize_nf4, rtn,
                                   quant_state_size_bytes, unpack_codes)
 
@@ -59,6 +61,30 @@ def test_pack_unpack_exact(case):
     packed = pack_codes(codes, bits)
     assert packed.shape[0] == codes.shape[0] * bits // 8
     assert bool(jnp.all(unpack_codes(packed, bits, codes.shape[0]) == codes))
+
+
+@pytest.mark.parametrize("bits", [2, 3, 4, 8])
+def test_byte_order_dequant_is_a_permutation_of_row_order(bits):
+    """A stack of packed weights dequantized in byte order, times an input
+    whose features are put in that order, is the row-order product; the
+    weight itself is the row-order one with its rows permuted."""
+    m, n, g, E = 32, 12, 8, 3
+    rng = np.random.default_rng(bits)
+    ws = [quantize_int(jnp.asarray(rng.normal(size=(m, n)), jnp.float32),
+                       bits, g) for _ in range(E)]
+    packed = jnp.stack([pack_codes(c, bits) for c, _, _ in ws])
+    scales = jnp.stack([s for _, s, _ in ws])
+    zeros = jnp.stack([z for _, _, z in ws])
+    rows = jnp.stack([dequantize_int(c, s, z, g) for c, s, z in ws])
+    w = dequantize_packed_byte_order(packed, scales, zeros, bits, m)
+    assert w.shape == rows.shape
+    idx = np.asarray(byte_order(jnp.arange(m), bits))
+    np.testing.assert_array_equal(np.asarray(w), np.asarray(rows)[:, idx])
+    x = jnp.asarray(rng.normal(size=(E, 5, m)), jnp.float32)
+    np.testing.assert_allclose(
+        np.asarray(jnp.einsum("ecm,emn->ecn", byte_order(x, bits), w)),
+        np.asarray(jnp.einsum("ecm,emn->ecn", x, rows)),
+        rtol=1e-5, atol=1e-5)
 
 
 @settings(max_examples=20, deadline=None)
